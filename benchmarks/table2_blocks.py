@@ -1,9 +1,9 @@
 """Paper Table 2: characteristics of the convolution blocks.
 
 Reports, per registered block at the 8/8-bit design point: wall-time per
-call (CPU-interpret — correctness path), MXU vs VPU resource split from
-the op census, and convolutions per grid step — reproducing the paper's
-DSP/logic trade-off rows.  Iterates the ``repro.blocks`` registry, so a
+call (interpreted on the CPU, compiled on a TPU), MXU vs VPU resource
+split from the op census, and convolutions per grid step — reproducing
+the paper's DSP/logic trade-off rows.  Iterates the ``repro.blocks`` registry, so a
 newly registered block shows up in the table automatically.
 """
 

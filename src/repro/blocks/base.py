@@ -90,7 +90,9 @@ class ConvBlock:
 
     def kernel_body(self, *, tile_h: int, w: int, data_bits: int,
                     coeff_bits: int):
-        """Pallas kernel body for one padded row-tile (subclasses)."""
+        """Pallas kernel body for one padded row-tile (subclasses).  It
+        receives the weights as one row of 9 taps per coefficient plane
+        — (1, 9), or (2, 9) for dual-output blocks."""
         raise NotImplementedError
 
     def _validate(self, x, w, data_bits: int, coeff_bits: int,
@@ -109,14 +111,13 @@ class ConvBlock:
                 f"tile_h={tile_h}")
 
     def apply(self, x, w, *, data_bits: int, coeff_bits: int,
-              tile_h: int = 16, interpret: bool = True):
+              tile_h: int = 16):
         """One plane through the Pallas kernel.  x: (H, W) container int;
         w: ``weight_shape()``.  Returns int32 'same'-padded conv output —
         (H, W), or (2, H, W) for dual-output blocks."""
         self._validate(x, w, data_bits, coeff_bits, tile_h)
         return _apply_one(self, x, w, data_bits=data_bits,
-                          coeff_bits=coeff_bits, tile_h=tile_h,
-                          interpret=interpret)
+                          coeff_bits=coeff_bits, tile_h=tile_h)
 
     def reference(self, x, w):
         """Pure-jnp oracle for ``apply`` (exact integer arithmetic)."""
@@ -126,7 +127,7 @@ class ConvBlock:
         return ref.conv2d_3x3_ref(x, w)
 
     def apply_batched(self, x, w, *, data_bits: int, coeff_bits: int,
-                      tile_h: int = 16, interpret: bool = True):
+                      tile_h: int = 16):
         """One CNN layer in a single jitted call.  x: (H, W, in_ch)
         container int, or an (N, H, W, in_ch) image batch; w: (out_ch,
         in_ch, 3, 3).  Returns the exact int32 accumulator (out_ch, H, W)
@@ -152,14 +153,12 @@ class ConvBlock:
                 f"tile_h={tile_h}")
         if x.ndim == 4:
             return _apply_batched_n(self, x, w, data_bits=data_bits,
-                                    coeff_bits=coeff_bits, tile_h=tile_h,
-                                    interpret=interpret)
+                                    coeff_bits=coeff_bits, tile_h=tile_h)
         return _apply_batched(self, x, w, data_bits=data_bits,
-                              coeff_bits=coeff_bits, tile_h=tile_h,
-                              interpret=interpret)
+                              coeff_bits=coeff_bits, tile_h=tile_h)
 
     def batched_layer(self, x, w, *, data_bits: int, coeff_bits: int,
-                      tile_h: int = 16, interpret: bool = True):
+                      tile_h: int = 16):
         """Whole-batch layer execution: x (N, H, W, in_ch) → exact int32
         (N, out_ch, H, W).  Default: outer ``jax.vmap`` over the
         single-image plane-vmapped path — correct for any block.  The
@@ -168,34 +167,30 @@ class ConvBlock:
         integer math); the multiply-free Conv1 keeps the default."""
         def one(img):
             return _apply_batched(self, img, w, data_bits=data_bits,
-                                  coeff_bits=coeff_bits, tile_h=tile_h,
-                                  interpret=interpret)
+                                  coeff_bits=coeff_bits, tile_h=tile_h)
         return jax.vmap(one)(x)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block", "data_bits", "coeff_bits", "tile_h", "interpret"))
-def _apply_one(block: ConvBlock, x, w, *, data_bits, coeff_bits, tile_h,
-               interpret):
+    "block", "data_bits", "coeff_bits", "tile_h"))
+def _apply_one(block: ConvBlock, x, w, *, data_bits, coeff_bits, tile_h):
     kern = block.kernel_body(tile_h=tile_h, w=x.shape[1],
                              data_bits=data_bits, coeff_bits=coeff_bits)
     return conv2d.run_block_kernel(
-        kern, x, w, n_out=2 if block.dual_output else 1,
-        tile_h=tile_h, interpret=interpret)
+        kern, x, w, n_out=2 if block.dual_output else 1, tile_h=tile_h)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block", "data_bits", "coeff_bits", "tile_h", "interpret"))
+    "block", "data_bits", "coeff_bits", "tile_h"))
 def _apply_batched(block: ConvBlock, x, w, *, data_bits, coeff_bits,
-                   tile_h, interpret):
+                   tile_h):
     h, wd, in_ch = x.shape
     out_ch = w.shape[0]
     planes = x.transpose(2, 0, 1)                      # (in_ch, H, W)
 
     def one(x2d, wk):
         return _apply_one(block, x2d, wk, data_bits=data_bits,
-                          coeff_bits=coeff_bits, tile_h=tile_h,
-                          interpret=interpret)
+                          coeff_bits=coeff_bits, tile_h=tile_h)
 
     # inner vmap pairs plane ic with weight [..., ic, :, :]; outer vmap
     # broadcasts the planes across output channels (or channel pairs)
@@ -215,12 +210,11 @@ def _apply_batched(block: ConvBlock, x, w, *, data_bits, coeff_bits,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block", "data_bits", "coeff_bits", "tile_h", "interpret"))
+    "block", "data_bits", "coeff_bits", "tile_h"))
 def _apply_batched_n(block: ConvBlock, x, w, *, data_bits, coeff_bits,
-                     tile_h, interpret):
+                     tile_h):
     return block.batched_layer(x, w, data_bits=data_bits,
-                               coeff_bits=coeff_bits, tile_h=tile_h,
-                               interpret=interpret)
+                               coeff_bits=coeff_bits, tile_h=tile_h)
 
 
 # ---------------------------------------------------------------------------
@@ -279,9 +273,7 @@ def packed_dot_layer(x, w, *, data_bits: int, coeff_bits: int):
         pat, packed.transpose(1, 2, 0),
         (((3,), (1,)), ((1,), (0,))),
         preferred_element_type=jnp.int32)
-    half = jnp.int32(1 << (s - 1))
-    lo = ((acc + half) & ((1 << s) - 1)) - half        # signed low field
-    hi = (acc - lo) >> s
+    hi, lo = conv2d.split_fields(acc, s)
     out = jnp.stack([jnp.sum(hi, axis=0), jnp.sum(lo, axis=0)], axis=-1)
     return out.reshape(n, h * wd, pairs * 2)[..., :oc] \
         .reshape(n, h, wd, oc).transpose(0, 3, 1, 2)

@@ -46,8 +46,7 @@ class Conv2Block(ConvBlock):
         return _partial(conv2d.conv2_kernel, tile_h=tile_h, w=w,
                         data_bits=data_bits, coeff_bits=coeff_bits)
 
-    def batched_layer(self, x, w, *, data_bits, coeff_bits, tile_h=16,
-                      interpret=True):
+    def batched_layer(self, x, w, *, data_bits, coeff_bits, tile_h=16):
         return fused_dot_layer(x, w, data_bits=data_bits,
                                coeff_bits=coeff_bits)
 
@@ -66,8 +65,7 @@ class Conv3Block(ConvBlock):
         return _partial(conv2d.conv3_kernel, tile_h=tile_h, w=w,
                         data_bits=data_bits, coeff_bits=coeff_bits)
 
-    def batched_layer(self, x, w, *, data_bits, coeff_bits, tile_h=16,
-                      interpret=True):
+    def batched_layer(self, x, w, *, data_bits, coeff_bits, tile_h=16):
         if self.packed_ok(data_bits, coeff_bits):
             return packed_dot_layer(x, w, data_bits=data_bits,
                                     coeff_bits=coeff_bits)
@@ -85,8 +83,7 @@ class Conv4Block(ConvBlock):
         return _partial(conv2d.conv4_kernel, tile_h=tile_h, w=w,
                         data_bits=data_bits, coeff_bits=coeff_bits)
 
-    def batched_layer(self, x, w, *, data_bits, coeff_bits, tile_h=16,
-                      interpret=True):
+    def batched_layer(self, x, w, *, data_bits, coeff_bits, tile_h=16):
         return fused_dot_layer(x, w, data_bits=data_bits,
                                coeff_bits=coeff_bits)
 

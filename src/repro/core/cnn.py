@@ -81,11 +81,12 @@ def quickstart_cnn_config() -> CNNConfig:
     ), img_h=32, img_w=128)
 
 
-# fitted-model memo for the default sweep, keyed on the sweep schema
-# version: repeated planning/serving calls (choose_blocks, the CNN serve
-# engine, benchmarks) share ONE multi-second sweep + fit per process; a
-# SWEEP_SCHEMA_VERSION bump naturally invalidates the entry
-_FITTED_MODELS: Dict[int, allocate.BlockModels] = {}
+# fitted-model memo for the default sweep, keyed on the sweep's schema
+# version and traced-source digest: repeated planning/serving calls
+# (choose_blocks, the CNN serve engine, benchmarks) share ONE
+# multi-second sweep + fit per process; a change to either key
+# naturally invalidates the entry
+_FITTED_MODELS: Dict[tuple, allocate.BlockModels] = {}
 
 
 def fitted_block_models(rows=None) -> allocate.BlockModels:
@@ -94,7 +95,7 @@ def fitted_block_models(rows=None) -> allocate.BlockModels:
     process-wide memoized fit of the default sweep."""
     if rows is not None:
         return allocate.BlockModels.fit(rows)
-    key = synth.SWEEP_SCHEMA_VERSION
+    key = synth.sweep_key()
     if key not in _FITTED_MODELS:
         _FITTED_MODELS[key] = allocate.BlockModels.fit(synth.run_sweep())
     return _FITTED_MODELS[key]
@@ -174,24 +175,35 @@ def cnn_forward(params, x, cfg: CNNConfig, blocks: Sequence[BlockLike],
     paper's 2-convolutions-per-step semantics.
 
     ``mesh``: optional device mesh for data-parallel serving — every
-    layer's batched activation is constrained to the batch sharding from
-    ``repro.parallel.sharding.cnn_batch_sharding`` (batch dimension over
-    the data axes).  Only meaningful for 4-D inputs under ``jax.jit``
-    (the serve engine's step)."""
-    sharding = None
-    if mesh is not None and x.ndim == 4:
-        from repro.parallel.sharding import cnn_batch_sharding
-        sharding = cnn_batch_sharding(mesh, x.shape[0])
-        x = jax.lax.with_sharding_constraint(x, sharding)
+    layer runs on each device's share of an (N, H, W, C) batch
+    (``cnn_layer``).  A single image ignores it."""
+    mesh = mesh if x.ndim == 4 else None
     act = x
     for spec, w, block in zip(cfg.layers, params, blocks):
-        blk = get_block(block)
-        acc = blk.apply_batched(act, w, data_bits=spec.data_bits,
-                                coeff_bits=spec.coeff_bits)
-        act = _requantize(acc, spec)
-        if sharding is not None:
-            act = jax.lax.with_sharding_constraint(act, sharding)
+        act = cnn_layer(spec, block, mesh)(w, act)
     return act
+
+
+def cnn_layer(spec: ConvLayerSpec, block: BlockLike, mesh=None):
+    """One layer as ``(w, x) -> activation``: ONE ``apply_batched`` call
+    plus the requantize.  With ``mesh``, each device runs the layer on
+    its share of the batch (``repro.parallel.sharding.
+    cnn_data_parallel``)."""
+    blk = get_block(block)
+
+    def layer(w, x):
+        acc = blk.apply_batched(x, w, data_bits=spec.data_bits,
+                                coeff_bits=spec.coeff_bits)
+        return _requantize(acc, spec)
+
+    if mesh is None:
+        return layer
+
+    def sharded(w, x):
+        from repro.parallel.sharding import cnn_data_parallel
+        return cnn_data_parallel(layer, mesh, x.shape[0])(w, x)
+
+    return sharded
 
 
 def cnn_forward_loop(params, x, cfg: CNNConfig,

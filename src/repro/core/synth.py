@@ -20,6 +20,7 @@ Resource classes and their FPGA counterparts:
 
 from __future__ import annotations
 
+import hashlib
 import json
 from pathlib import Path
 from typing import Dict, List
@@ -98,19 +99,44 @@ def synth_one(block: BlockLike, data_bits: int, coeff_bits: int,
 # bump when row semantics change (e.g. the _vmem_bytes container-width
 # model) so pre-existing caches regenerate instead of silently serving
 # stale numbers; legacy bare-list caches count as version 0
-SWEEP_SCHEMA_VERSION = 2
+SWEEP_SCHEMA_VERSION = 3
+
+_PKG = Path(__file__).resolve().parents[1]
+# what a sweep row is traced from: the kernel bodies, the blocks that
+# wrap them, the op census, and this module
+TRACED_SOURCES = ("kernels/conv2d.py", "blocks/*.py", "core/hloscan.py",
+                  "core/synth.py")
+
+
+def sources_digest() -> str:
+    """SHA-256 over the sources the sweep traces: a cache written by
+    any other version of them is never served."""
+    h = hashlib.sha256()
+    for pattern in TRACED_SOURCES:
+        for path in sorted(_PKG.glob(pattern)):
+            h.update(path.relative_to(_PKG).as_posix().encode())
+            h.update(path.read_bytes())
+    return h.hexdigest()
+
+
+def sweep_key() -> tuple:
+    """Identity of the rows ``run_sweep`` produces: schema version and
+    the digest of the traced sources."""
+    return SWEEP_SCHEMA_VERSION, sources_digest()
 
 
 def run_sweep(cfg: ConvSweepConfig = SWEEP,
               cache_path: str | Path = "benchmarks/_cache/synth.json",
               force: bool = False) -> List[dict]:
     cache = Path(cache_path)
+    version, digest = sweep_key()
     if cache.exists() and not force:
         payload = json.loads(cache.read_text())
         if (isinstance(payload, dict)
-                and payload.get("version") == SWEEP_SCHEMA_VERSION):
+                and payload.get("version") == version
+                and payload.get("sources") == digest):
             return payload["rows"]
-        # stale or pre-versioning cache → fall through and re-sweep
+        # stale, pre-versioning or other-source cache → re-sweep
     rows = []
     for block in cfg.blocks:
         blk = get_block(block)
@@ -120,7 +146,7 @@ def run_sweep(cfg: ConvSweepConfig = SWEEP,
                 row.update(synth_one(blk, d, c, cfg))
                 rows.append(row)
     cache.parent.mkdir(parents=True, exist_ok=True)
-    cache.write_text(json.dumps({"version": SWEEP_SCHEMA_VERSION,
+    cache.write_text(json.dumps({"version": version, "sources": digest,
                                  "rows": rows}))
     return rows
 

@@ -7,7 +7,6 @@ the ``repro.blocks`` registry — use ``get_block(name).apply(...)`` /
 
 from __future__ import annotations
 
-import functools
 import warnings
 
 import jax
@@ -25,8 +24,7 @@ def quantize_fixed(x, bits: int, *, signed: bool = True):
     return q.astype(conv2d.container_dtype(bits))
 
 
-def conv_block(block, x, w, *, data_bits, coeff_bits, tile_h=16,
-               interpret=True):
+def conv_block(block, x, w, *, data_bits, coeff_bits, tile_h=16):
     """Deprecated string-dispatch shim; use
     ``repro.blocks.get_block(block).apply(...)``."""
     warnings.warn(
@@ -39,7 +37,7 @@ def conv_block(block, x, w, *, data_bits, coeff_bits, tile_h=16,
     except KeyError as e:       # preserve the seed contract (ValueError)
         raise ValueError(f"unknown block {block!r}") from e
     return blk.apply(x, w, data_bits=data_bits, coeff_bits=coeff_bits,
-                     tile_h=tile_h, interpret=interpret)
+                     tile_h=tile_h)
 
 
 def conv_block_ref(block, x, w, **kw):
@@ -53,9 +51,10 @@ def conv_block_ref(block, x, w, **kw):
     return get_block(block).reference(x, w)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def causal_conv1d(x, w, interpret=True):
-    return conv1d.causal_conv1d_pallas(x, w, interpret=interpret)
+@jax.jit
+def causal_conv1d(x, w):
+    return conv1d.causal_conv1d_pallas(x, w,
+                                       interpret=conv2d.interpret_mode())
 
 
 def causal_conv1d_ref(x, w, conv_state=None):

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 
 from repro.configs import SHAPES, cell_is_runnable, get_config, list_archs
 from repro.core import hloscan
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import auto_mesh, make_production_mesh
 from repro.models import build_model
 from repro.optim import AdamWConfig, adamw_init
 from repro.parallel.sharding import ShardingRules, choose_mode
@@ -61,7 +61,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
         # the topology is fixed, the (data, model) factorization is not.
         axes = (("pod", "data", "model") if len(mesh_shape) == 3
                 else ("data", "model"))
-        mesh = jax.make_mesh(tuple(mesh_shape), axes)
+        mesh = auto_mesh(mesh_shape, axes)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     model = build_model(cfg)

@@ -92,7 +92,8 @@ def _ops_cache(args):
         return None
     from repro.ops import PersistentExecutableCache
     cache = PersistentExecutableCache(args.cache_dir)
-    print(f"[ops] persistent executable cache at {args.cache_dir!r}")
+    print(f"[ops] persistent executable cache at {args.cache_dir!r} "
+          f"(replaces JAX's compilation cache)")
     return cache
 
 
@@ -176,18 +177,25 @@ def run_lm(args) -> None:
     _ops_finish(tracker, sampler)
 
 
-def _cnn_plan(args):
-    """Load or compute the deployment plan the CNN workloads serve."""
-    from repro import runtime
+def quickstart_cnn_plan(device: str, cfg=None):
+    """The planner's deployment of the quickstart CNN (or ``cfg``) on
+    one catalog device profile — what the CNN workloads serve unless a
+    plan artifact is given."""
     from repro.core import allocate, deploy
     from repro.core.cnn import fitted_block_models, quickstart_cnn_config
 
+    cfg = quickstart_cnn_config() if cfg is None else cfg
+    return deploy.plan_deployment(cfg, fitted_block_models(),
+                                  allocate.get_device(device), target=0.8,
+                                  on_infeasible="fallback")
+
+
+def _cnn_plan(args):
+    """Load or compute the deployment plan the CNN workloads serve."""
+    from repro import runtime
+
     def compute():
-        cfg = quickstart_cnn_config()
-        bm = fitted_block_models()
-        device = allocate.get_device(args.device)
-        return deploy.plan_deployment(cfg, bm, device, target=0.8,
-                                      on_infeasible="fallback")
+        return quickstart_cnn_plan(args.device)
 
     if args.plan:
         plan = runtime.load_plan(args.plan)
@@ -475,13 +483,10 @@ def run_cnn_fleet(args) -> None:
     by ``--router``, per-tier tail latency reported.  ``--drain``
     gracefully drains the v5e worker halfway through — queued requests
     re-route, in-flight batches finish, nothing is lost."""
-    from repro.core import allocate, deploy
-    from repro.core.cnn import fitted_block_models, quickstart_cnn_config
     from repro.fleet import DEFAULT_TIERS, Fleet, FleetWorker
-    from repro.serve import AsyncCNNGateway, AsyncServeConfig
+    from repro.serve import (AsyncCNNGateway, AsyncServeConfig,
+                             DeadlineExpired, GatewayBacklog)
 
-    cfg = quickstart_cnn_config()
-    bm = fitted_block_models()
     profiles = ("edge", "v5e", "v5p")
     # one shared persistent cache across all profile gateways: the disk
     # entries are content-addressed by layer key, so layers identical
@@ -491,8 +496,7 @@ def run_cnn_fleet(args) -> None:
     t0 = time.time()
     workers = []
     for name in profiles:
-        plan = deploy.plan_deployment(cfg, bm, allocate.get_device(name),
-                                      target=0.8, on_infeasible="fallback")
+        plan = quickstart_cnn_plan(name)
         gw = AsyncCNNGateway.from_plan(
             plan, AsyncServeConfig(max_batch=args.max_batch,
                                    max_pending=args.max_pending),
@@ -541,8 +545,8 @@ def run_cnn_fleet(args) -> None:
                                              deadline=spec.deadline_s)
                     await fut
                     per_tier[tier].append(time.monotonic() - t_sub)
-                except Exception:       # noqa: BLE001 — expired/shed
-                    expired += 1
+                except (DeadlineExpired, GatewayBacklog):   # incl. the
+                    expired += 1        # fleet's FleetSaturated shed
 
             async def drainer():
                 await asyncio.sleep(arrivals[args.requests // 2])
@@ -655,6 +659,9 @@ def main():
                          "(repro.ops.JsonlTracker; all workloads)")
     args = ap.parse_args()
     _apply_store_root(args)
+    from repro.ops import enable_jax_compilation_cache
+    print(f"[serve] JAX compilation cache at "
+          f"{enable_jax_compilation_cache()!r}")
     if args.arch is None:
         args.arch = ("qwen3-moe-30b-a3b" if args.workload == "moe"
                      else "llama3.2-3b")

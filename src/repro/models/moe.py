@@ -289,7 +289,6 @@ def _combine_shardmap(expert_out, slot, keep, vals, *, nl, e, cap, d, k):
     import functools
 
     from jax._src.mesh import thread_resources
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = thread_resources.env.physical_mesh
@@ -323,11 +322,11 @@ def _combine_shardmap(expert_out, slot, keep, vals, *, nl, e, cap, d, k):
         part = jax.vmap(one)(eo, sl, kp, vl)
         return jax.lax.psum(part.astype(jnp.bfloat16), "model")
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dpa, "model", None, None), P(dpa, None), P(dpa, None),
                   P(dpa, None, None)),
-        out_specs=P(dpa, None, None), check_rep=False)
+        out_specs=P(dpa, None, None), check_vma=False)
     return fn(expert_out, slot, keep, vals).astype(jnp.float32)
 
 
@@ -341,7 +340,6 @@ def _dispatch_shardmap(xg, slot, keep, *, nl, e, cap, d, k):
     B4 combine.
     """
     from jax._src.mesh import thread_resources
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = thread_resources.env.physical_mesh
@@ -373,10 +371,11 @@ def _dispatch_shardmap(xg, slot, keep, *, nl, e, cap, d, k):
 
         return jax.vmap(one)(xl, sl, kp)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(dpa, None, None), P(dpa, None),
-                             P(dpa, None)),
-                   out_specs=P(dpa, "model", None, None), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(dpa, None, None), P(dpa, None),
+                                 P(dpa, None)),
+                       out_specs=P(dpa, "model", None, None),
+                       check_vma=False)
     return fn(xg, slot, keep)
 
 

@@ -25,7 +25,7 @@ durable state they read from and report into.  See ``docs/ops.md``.
 """
 
 from repro.ops.cache import (CACHE_FORMAT_VERSION, PersistentExecutableCache,
-                             cache_fingerprint)
+                             cache_fingerprint, enable_jax_compilation_cache)
 from repro.ops.root import Lease, LeaseHeld, StoreRoot
 from repro.ops.store import (PlanCorrupt, PlanNotFound, PlanRetired,
                              PlanStore, PlanStoreError)
@@ -36,7 +36,7 @@ __all__ = [
     "PlanStore", "PlanStoreError", "PlanNotFound", "PlanRetired",
     "PlanCorrupt",
     "PersistentExecutableCache", "cache_fingerprint",
-    "CACHE_FORMAT_VERSION",
+    "CACHE_FORMAT_VERSION", "enable_jax_compilation_cache",
     "StoreRoot", "Lease", "LeaseHeld",
     "Tracker", "NullTracker", "JsonlTracker", "StatsSampler",
     "TrackerLog", "read_log", "read_events",

@@ -35,6 +35,12 @@ Keying and safety:
 
 Executables that are not jax ``Compiled`` objects (some backends cache
 plain callables) are skipped — they compile live, as before.
+
+JAX's own persistent compilation cache is chosen here too, and only
+here: a launcher turns it on with ``enable_jax_compilation_cache``, and
+building this tier turns it off for the whole process
+(``disable_jax_compilation_cache``) — the tier must serialize fresh
+compiles, never executables JAX loaded from its own cache.
 """
 
 from __future__ import annotations
@@ -46,9 +52,36 @@ from pathlib import Path
 from typing import Callable, Optional, Tuple, Union
 
 import jax
+from jax.experimental.compilation_cache import compilation_cache
 
 from repro.runtime.compiled import ExecutableCache
 from repro.runtime.plan_io import _fsync_dir
+
+#: JAX's compilation cache when ``$JAX_COMPILATION_CACHE_DIR`` is unset:
+#: a fixed directory of the checkout (the path is part of the cache key)
+CHECKOUT_JAX_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_jax_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return where it
+    lives.  ``$JAX_COMPILATION_CACHE_DIR``, when set, is left to JAX,
+    which reads it itself; otherwise the cache goes to the fixed
+    ``<checkout>/.jax_cache``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_JAX_CACHE))
+    return str(CHECKOUT_JAX_CACHE)
+
+
+def disable_jax_compilation_cache() -> None:
+    """Turn JAX's persistent compilation cache off for the rest of the
+    process (JAX decides once per process whether it uses the cache, so
+    it cannot be turned off for one compile).  An executable JAX loaded
+    from its cache serializes without its code: XLA:CPU fails when the
+    restored copy runs ("Function ... not found")."""
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
 
 __all__ = ["PersistentExecutableCache", "cache_fingerprint",
            "CACHE_FORMAT_VERSION"]
@@ -87,11 +120,16 @@ class PersistentExecutableCache(ExecutableCache):
     Inherits single-flight semantics: a key being loaded/compiled by
     one thread is waited on by the others.  ``stats()`` gains
     ``disk_hits`` / ``disk_stores`` / ``disk_errors``.
+
+    Process-wide effect: building one turns JAX's own persistent
+    compilation cache off for every later compile of the process
+    (``disable_jax_compilation_cache``); this tier replaces it.
     """
 
     def __init__(self, cache_dir: Union[str, Path], *,
                  on_event: Optional[Callable[[str, dict], None]] = None):
         super().__init__(on_event=on_event)
+        disable_jax_compilation_cache()
         self.cache_dir = Path(cache_dir)
         self.cache_dir.mkdir(parents=True, exist_ok=True)
         self.fingerprint = cache_fingerprint()

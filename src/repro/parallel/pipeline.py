@@ -23,7 +23,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_forward(stage_fn: Callable, stage_params, x_microbatches,
@@ -71,9 +70,9 @@ def pipeline_forward(stage_fn: Callable, stage_params, x_microbatches,
         return gathered[n_stages - 1]
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(stage_local, mesh=mesh,
-                   in_specs=(spec_params, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(stage_local, mesh=mesh,
+                       in_specs=(spec_params, P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x_microbatches)
 
 

@@ -229,10 +229,11 @@ def choose_mode(cfg: ModelConfig, mesh: Mesh) -> str:
 # The CNN hot path has no tensor-parallel dimension worth sharding (whole
 # layers fit one chip by construction — that is the deployment planner's
 # job), so serving parallelism is pure DP: the (N, H, W, C) batch
-# dimension over the data axes.  Used by ``core.cnn.cnn_forward(mesh=)``
-# and the AOT bucketed runtime (``repro.runtime.CompiledCNN``, which the
-# serve engine executes through): each batch-bucket executable places
-# and constrains its bucket-sized batch with ``cnn_batch_sharding``.
+# dimension over the data axes.  ``core.cnn.cnn_layer`` — the layer of
+# ``cnn_forward(mesh=)`` and of the AOT bucketed runtime
+# (``repro.runtime.CompiledCNN``, which the serve engine executes
+# through) — runs each device's share under ``cnn_data_parallel``; the
+# runtime places its bucket-sized batch with ``cnn_batch_sharding``.
 # ---------------------------------------------------------------------------
 
 def cnn_data_mesh(devices: Optional[Sequence] = None) -> Mesh:
@@ -256,3 +257,14 @@ def cnn_batch_sharding(mesh: Mesh, batch: int) -> NamedSharding:
     if _divides(batch, size):
         lead = axes if len(axes) > 1 else axes[0]
     return NamedSharding(mesh, P(lead, None, None, None))
+
+
+def cnn_data_parallel(layer, mesh: Mesh, batch: int):
+    """``layer(w, x)`` run on each device's share of the image batch
+    ``x`` (split as ``cnn_batch_sharding`` splits it) with the weights
+    replicated.  Explicit per-device execution: the compiler cannot
+    partition a Pallas (Mosaic) kernel, and a CNN layer needs no
+    communication across the batch."""
+    spec = cnn_batch_sharding(mesh, batch).spec
+    return jax.shard_map(layer, mesh=mesh, in_specs=(P(), spec),
+                         out_specs=spec, check_vma=False)

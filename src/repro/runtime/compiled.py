@@ -34,9 +34,10 @@ that never ran the planner) and executes exactly the per-layer
 are bit-exact against ``cnn_forward_ref`` — bucket padding rides along
 as zero images that are sliced off, never summed.
 
-Data parallelism: pass a device mesh and each bucket's executable
-constrains its batch to ``sharding.cnn_batch_sharding`` (batch over the
-data axes when divisible, replicated otherwise).
+Data parallelism: pass a device mesh and each bucket's executable runs
+its layer on every device's share of the batch
+(``sharding.cnn_data_parallel``: batch over the data axes when
+divisible, replicated otherwise).
 
 Multi-plan serving: executables live in an ``ExecutableCache`` — pass
 one cache to several ``CompiledModel`` instances (the async gateway
@@ -61,7 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.blocks import BlockLike, get_block
-from repro.core.cnn import CNNConfig, _requantize, init_cnn
+from repro.core.cnn import CNNConfig, cnn_layer, init_cnn
 from repro.kernels import conv2d
 
 
@@ -488,18 +489,7 @@ class CompiledCNN(CompiledModel):
                 self.cfg.img_h, self.cfg.img_w, self._mesh_token, bucket)
 
     def _layer_fn(self, i: int):
-        spec, blk, mesh = self.cfg.layers[i], self.blocks[i], self.mesh
-
-        def layer(w, x):
-            if mesh is not None:
-                from repro.parallel.sharding import cnn_batch_sharding
-                sh = cnn_batch_sharding(mesh, x.shape[0])
-                x = jax.lax.with_sharding_constraint(x, sh)
-            acc = blk.apply_batched(x, w, data_bits=spec.data_bits,
-                                    coeff_bits=spec.coeff_bits)
-            return _requantize(acc, spec)
-
-        return layer
+        return cnn_layer(self.cfg.layers[i], self.blocks[i], self.mesh)
 
     def _layer_params(self, i: int):
         return self.params[i]

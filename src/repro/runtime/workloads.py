@@ -72,6 +72,14 @@ MOE_BLOCK_NAME = "moe_ffn"
 #: rate resources (additive across layers); vmem_bytes is the capacity
 _RATE_RESOURCES = tuple(r for r in BUDGET_RESOURCES if r != "vmem_bytes")
 
+# op by op, every full-size intermediate is allocated when its op is
+# queued and freed only after its consumer ran, so at published widths
+# the queued temporaries of a few layers would fill the device: the
+# quantization runs as one XLA program (bit-identical), and each layer's
+# draw is waited for before the next is queued (jitting the draw would
+# fuse its scaling and change the weights)
+_quantize_moe = jax.jit(moe_mod.quantize_moe_params, static_argnums=1)
+
 
 # ---------------------------------------------------------------------------
 # the protocol + registry
@@ -323,13 +331,16 @@ class MoEWorkloadSpec(WorkloadSpec):
         """Per-layer ``init_moe`` draws (float32), expert weights
         fake-quantized to each layer's ``coeff_bits`` grid unless
         ``quantized=False`` (the float oracle draw)."""
-        ks = split_keys(key, len(self.layers))
-        out = []
-        for i, s in enumerate(self.layers):
-            p = moe_mod.init_moe(ks[i], self.layer_cfg(i))
-            out.append(moe_mod.quantize_moe_params(p, s.coeff_bits)
-                       if quantized else p)
-        return out
+        return list(self.iter_params(key, quantized=quantized))
+
+    def iter_params(self, key, *, quantized: bool = True):
+        """``init_params`` one layer at a time, drawn only when asked
+        for: a caller that drops each layer before the next holds one
+        layer's weights (GBs at published widths), not the stack's."""
+        for i, k in enumerate(split_keys(key, len(self.layers))):
+            p = jax.block_until_ready(moe_mod.init_moe(k, self.layer_cfg(i)))
+            yield (_quantize_moe(p, self.layers[i].coeff_bits)
+                   if quantized else p)
 
 
 @dataclass(frozen=True)
@@ -349,6 +360,18 @@ class _MoELayerModelCfg:
     @property
     def jnp_dtype(self):
         return jnp.float32
+
+
+def _route_per_block(p, x, cfg):
+    """One MoE layer over a batch of token blocks with each block routed
+    on its own (``moe_groups`` = blocks, so expert capacity is per
+    block): a block's output never depends on which blocks share its
+    dispatch or on bucket padding — the routing twin of ``_fake_quant``'s
+    per-token scale.  The aux (load-balancing) loss is a training
+    quantity; inference drops it."""
+    y, _aux = moe_mod.moe_layer(
+        p, x, dataclasses.replace(cfg, moe_groups=x.shape[0]))
+    return y
 
 
 def _fake_quant(x, bits: int):
@@ -419,11 +442,8 @@ class CompiledMoE(CompiledModel):
         data_bits = self.spec.layers[i].data_bits
 
         def layer(p, x):
-            # residual MoE block over the quantized activation grid;
-            # the aux (load-balancing) loss is a training quantity —
-            # inference drops it
-            y, _aux = moe_mod.moe_layer(p, _fake_quant(x, data_bits), cfg)
-            return x + y
+            # residual MoE block over the quantized activation grid
+            return x + _route_per_block(p, _fake_quant(x, data_bits), cfg)
 
         return layer
 
@@ -610,8 +630,7 @@ def _eager_forward(spec: MoEWorkloadSpec, params, x, *,
     for i in range(len(spec.layers)):
         xi = (_fake_quant(act, spec.layers[i].data_bits)
               if quant_act else act)
-        y, _ = moe_mod.moe_layer(params[i], xi, spec.layer_cfg(i))
-        act = act + y
+        act = act + _route_per_block(params[i], xi, spec.layer_cfg(i))
     return act
 
 
@@ -631,14 +650,19 @@ def moe_quantization_error(spec: MoEWorkloadSpec, *, key=None,
     dense-reference oracle on a deterministic probe block (the per-plan
     Pareto axis — ``deploy.quantization_error``'s MoE twin)."""
     key = key if key is not None else jax.random.PRNGKey(0)
-    float_params = spec.init_params(key, quantized=False)
-    quant_params = [moe_mod.quantize_moe_params(p, s.coeff_bits)
-                    for p, s in zip(float_params, spec.layers)]
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.standard_normal(
         (1, spec.seq_len, spec.d_model)), jnp.float32)
-    yq = _eager_forward(spec, quant_params, x)
-    yf = _dense_ref_forward(spec, float_params, x)
+    # the two stacks advance layer by layer (the ops of _eager_forward
+    # and _dense_ref_forward), so one layer's float and quantized
+    # weights are live at a time
+    yq = yf = x
+    for i, pf in enumerate(spec.iter_params(key, quantized=False)):
+        s, cfg = spec.layers[i], spec.layer_cfg(i)
+        pq = _quantize_moe(pf, s.coeff_bits)
+        yq = yq + _route_per_block(pq, _fake_quant(yq, s.data_bits), cfg)
+        yf = yf + moe_mod.moe_layer_dense_ref(pf, yf, cfg)
+        del pf, pq
     num = float(jnp.sqrt(jnp.mean((yq - yf) ** 2)))
     den = float(jnp.sqrt(jnp.mean(yf ** 2)))
     return num / max(den, 1e-9)

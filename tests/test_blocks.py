@@ -11,9 +11,71 @@ from repro.blocks import (BIT_RANGE, ConvBlock, Conv2Block, get_block,
                           list_blocks, register_block, unregister_block)
 from repro.core.cnn import (CNNConfig, ConvLayerSpec, choose_blocks,
                             cnn_forward, cnn_forward_ref, init_cnn)
-from repro.kernels import ops, ref
+from repro.kernels import conv2d, ops, ref
 
 DESIGN_POINTS = [(4, 4), (8, 8), (8, 10)]
+
+
+# ---------------------------------------------------------------------------
+# interpret mode: chosen once, from the backend
+# ---------------------------------------------------------------------------
+
+def _pallas_eqn(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for key in ("jaxpr", "call_jaxpr"):
+            sub = eqn.params.get(key)
+            if sub is not None:
+                found = _pallas_eqn(getattr(sub, "jaxpr", sub))
+                if found is not None:
+                    return found
+    return None
+
+
+def _traced_kernel(name="conv1", d=8, c=8):
+    blk = get_block(name)
+    return jax.make_jaxpr(
+        lambda x, w: blk.apply(x, w, data_bits=d, coeff_bits=c))(
+        jnp.zeros((32, 128), jnp.int8),
+        jnp.zeros(blk.weight_shape(c), jnp.int8)).jaxpr
+
+
+def test_interpret_mode_follows_backend(monkeypatch):
+    """Kernels compile on a TPU and interpret on every other backend;
+    no signature carries the choice."""
+    assert conv2d.interpret_mode() is (jax.default_backend() != "tpu")
+    for backend, interpret in (("tpu", False), ("cpu", True),
+                               ("gpu", True)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert conv2d.interpret_mode() is interpret
+    monkeypatch.undo()
+    # the CPU trace of the served block API carries the interpreter
+    assert _pallas_eqn(_traced_kernel()).params["interpret"] is True
+
+
+@pytest.mark.parametrize("name,d,c", [("conv1", 6, 4), ("conv3", 6, 4),
+                                      ("conv4", 12, 10)])
+def test_census_is_platform_independent(monkeypatch, name, d, c):
+    """The sweep's op census reads the kernel body, which is the same
+    whether the kernel is then compiled or interpreted."""
+    from repro.core import hloscan
+
+    blk = get_block(name)
+
+    def census():
+        jax.clear_caches()
+        return hloscan.jaxpr_resources(
+            lambda x, w: blk.apply(x, w, data_bits=d, coeff_bits=c),
+            jnp.zeros((64, 128), conv2d.container_dtype(d)),
+            jnp.zeros(blk.weight_shape(c), conv2d.container_dtype(c)))
+
+    interpreted = census()
+    monkeypatch.setattr(conv2d, "interpret_mode", lambda: False)
+    compiled = census()
+    monkeypatch.undo()
+    jax.clear_caches()
+    assert compiled == interpreted
 
 
 # ---------------------------------------------------------------------------
@@ -147,6 +209,54 @@ def test_apply_batched_nhwc_bit_exact_property(name, point, n, seed):
             for j in range(ic))
         for o in range(oc)]) for i in range(n)])
     np.testing.assert_array_equal(np.asarray(acc), np.asarray(accr))
+
+
+# design points at the kernels' arithmetic edges: the last narrow
+# (two-per-lane) conv1 accumulator (d + c = 11), the last packed conv3
+# point (d + c = 12, nine data bits → two data limbs), one-limb/two-limb
+# MXU operands, and the widest point (three limbs each)
+EXTREME_POINTS = [(3, 8), (6, 5), (9, 3), (8, 9), (15, 15), (16, 16)]
+
+
+@pytest.mark.parametrize("d,c", EXTREME_POINTS)
+@pytest.mark.parametrize("name", ["conv1", "conv2", "conv3", "conv4"])
+def test_apply_extreme_values_bit_exact(name, d, c):
+    """Full-scale operands (every datum and coefficient at the most
+    negative or most positive code) drive every accumulator, field and
+    limb to its bound: the kernels stay equal to the oracle."""
+    blk = get_block(name)
+    lo_d, hi_d = -(1 << (d - 1)), (1 << (d - 1)) - 1
+    lo_c, hi_c = -(1 << (c - 1)), (1 << (c - 1)) - 1
+    rng = np.random.default_rng(d * 17 + c)
+    x = jnp.asarray(rng.choice([lo_d, hi_d], (32, 128)),
+                    conv2d.container_dtype(d))
+    w = jnp.asarray(rng.choice([lo_c, hi_c], blk.weight_shape(c)),
+                    conv2d.container_dtype(c))
+    w = w.at[..., 0, 0].set(lo_c)
+    x = x.at[:8].set(lo_d)                 # a patch of all-minimum data
+    got = blk.apply(x, w, data_bits=d, coeff_bits=c)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(blk.reference(x, w)))
+
+
+@pytest.mark.parametrize("bits", [8, 9, 15, 16, 24])
+def test_limb_split_round_trips(bits):
+    """A ``bits``-bit operand split into int8 limbs recombines exactly,
+    and the limb dot equals the int32 dot."""
+    lo, hi = -(1 << (bits - 1)), (1 << (bits - 1)) - 1
+    v = jnp.asarray([[lo, lo + 1, -1, 0, 1, hi - 1, hi, 77]], jnp.int32)
+    limbs = conv2d._limbs(v, bits)
+    # 8 bits in one limb, then one 7-bit limb per 7 bits beyond
+    assert len(limbs) == {8: 1, 9: 2, 15: 2, 16: 3, 24: 4}[bits]
+    assert all(limb.dtype == jnp.int8 for limb in limbs)
+    back = sum(limb.astype(jnp.int32) << (conv2d.LIMB_BITS * k)
+               for k, limb in enumerate(limbs))
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(v))
+    col = jnp.asarray([[-128], [127], [1], [0], [-1], [3], [-5], [2]],
+                      jnp.int32)
+    want = np.asarray(v, np.int64) @ np.asarray(col, np.int64)
+    got = conv2d._limb_dot(limbs, conv2d._limbs(col, 8))
+    np.testing.assert_array_equal(np.asarray(got), want.astype(np.int32))
 
 
 def test_apply_batched_raw_accumulator():

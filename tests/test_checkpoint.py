@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import auto_mesh
 from repro.train.checkpoint import Checkpointer
 
 
@@ -69,7 +70,7 @@ def test_mesh_agnostic_restore(tmp_path):
     ck = Checkpointer(tmp_path)
     s = {"w": jnp.arange(16.0).reshape(4, 4)}
     ck.save(3, s)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     target = jax.device_put(jnp.zeros((4, 4)),
                             NamedSharding(mesh, P("data", None)))

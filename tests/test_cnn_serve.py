@@ -16,6 +16,7 @@ from repro.core import deploy
 from repro.core.cnn import (CNNConfig, ConvLayerSpec, cnn_forward_ref,
                             fitted_block_models, init_cnn)
 from repro.kernels import ops
+from repro.launch.mesh import auto_mesh
 from repro.parallel.sharding import cnn_batch_sharding, cnn_data_mesh
 from repro.serve import CNNEngine, CNNServeConfig, ImageRequest
 
@@ -292,7 +293,7 @@ def test_cnn_batch_sharding_divisibility():
     assert cnn_batch_sharding(mesh, 4 * n).spec \
         == P("data", None, None, None)
     # 2-D train-style mesh: batch over the data axis only
-    mesh2 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh2 = auto_mesh((1, 1), ("data", "model"))
     assert cnn_batch_sharding(mesh2, 8).spec == P("data", None, None, None)
 
 

@@ -57,8 +57,9 @@ def test_analyzer_on_scanned_sharded_matmul():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core import hloscan
+        from repro.launch.mesh import auto_mesh
 
-        mesh = jax.make_mesh((8,), ("m",))
+        mesh = auto_mesh((8,), ("m",))
         sh = NamedSharding(mesh, P(None, "m"))
         wsh = NamedSharding(mesh, P(None, None, "m"))
 

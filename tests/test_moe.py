@@ -114,6 +114,7 @@ def test_shardmap_dispatch_combine_multidevice():
         sys.path.insert(0, "src")
         import dataclasses, jax, jax.numpy as jnp
         from repro.configs import smoke_config
+        from repro.launch.mesh import auto_mesh
         from repro.models import moe as moe_mod
         cfg = smoke_config('qwen3-moe-30b-a3b').with_overrides(
             dtype='float32')
@@ -123,7 +124,7 @@ def test_shardmap_dispatch_combine_multidevice():
         p = moe_mod.init_moe(jax.random.PRNGKey(0), cfg)
         x = 0.1 * jax.random.normal(jax.random.PRNGKey(1),
                                     (4, 16, cfg.d_model))
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = auto_mesh((4, 2), ('data', 'model'))
         with mesh:
             out, _ = jax.jit(lambda p, x: moe_mod.moe_layer(p, x, cfg))(p, x)
             g = jax.jit(jax.grad(

@@ -131,6 +131,45 @@ def test_warm_restart_zero_recompiles(tmp_path, plan):
     np.testing.assert_array_equal(np.asarray(cold(x)), np.asarray(warm(x)))
 
 
+@pytest.fixture
+def warm_jax_cache(tmp_path):
+    """JAX's own persistent compilation cache on (every compile cached),
+    restored afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    compilation_cache.reset_cache()
+    for name, value in zip(names, (True, str(tmp_path / "jax"), 0, 0)):
+        jax.config.update(name, value)
+    yield
+    for name, value in saved.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
+
+
+def test_warm_restart_under_a_warm_jax_cache(tmp_path, plan,
+                                             warm_jax_cache):
+    """With JAX's persistent cache on and already holding every layer,
+    the disk tier still stores executables that run after a restart: it
+    turns JAX's cache off, so it serializes fresh compiles and never
+    copies JAX loaded from its cache (which XLA:CPU cannot run)."""
+    CompiledCNN.from_plan(plan, _cfg(), max_batch=2)   # fills JAX's cache
+    cold = CompiledCNN.from_plan(
+        plan, _cfg(), max_batch=2,
+        exec_cache=PersistentExecutableCache(tmp_path / "exe"))
+    warm = CompiledCNN.from_plan(
+        plan, _cfg(), max_batch=2,
+        exec_cache=PersistentExecutableCache(tmp_path / "exe"))
+    assert cold.compiles > 0 and warm.compiles == 0
+    x = np.stack([np.asarray(i, cold.in_dtype)
+                  for i in cold.sample_inputs(2, seed=3)])
+    np.testing.assert_array_equal(np.asarray(cold(x)), np.asarray(warm(x)))
+
+
 def test_fingerprint_mismatch_falls_back_to_compile(tmp_path, plan):
     cold = PersistentExecutableCache(tmp_path)
     CompiledCNN.from_plan(plan, _cfg(), max_batch=1, exec_cache=cold)

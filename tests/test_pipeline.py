@@ -21,6 +21,7 @@ def test_pipeline_matches_sequential():
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp
         import numpy as np
+        from repro.launch.mesh import auto_mesh
         from repro.parallel.pipeline import pipeline_forward
 
         S, M, mb, d = 4, 8, 2, 16
@@ -33,7 +34,7 @@ def test_pipeline_matches_sequential():
         def stage_fn(w, x):
             return jnp.tanh(x @ w)
 
-        mesh = jax.make_mesh((4,), ("pipe",))
+        mesh = auto_mesh((4,), ("pipe",))
         out = pipeline_forward(stage_fn, W, xs, mesh=mesh, axis="pipe")
 
         # sequential reference

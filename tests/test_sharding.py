@@ -10,13 +10,14 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
+from repro.launch.mesh import auto_mesh
 from repro.models import build_model
 from repro.parallel.sharding import ShardingRules, choose_mode
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def _spec_of(tree_spec, *path):
@@ -30,7 +31,7 @@ def test_granite_mqa_head_not_sharded():
     """kv=1 head cannot shard over model=16 → replicated; q heads (48)
     don't divide 16 either... 48 % 16 == 0 so they do."""
     cfg = get_config("granite-20b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     # emulate the production axis sizes through a fake mesh of size 1 but
     # checking the rule logic directly with tp_size patched
     rules = ShardingRules(cfg, mesh, mode="tp")
@@ -46,7 +47,7 @@ def test_granite_mqa_head_not_sharded():
 
 def test_gemma2_2b_heads_replicated():
     cfg = get_config("gemma2-2b")                  # 8 q heads < 16
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     rules = ShardingRules(cfg, mesh, mode="tp")
     rules.tp_size = 16
     spec = rules.params_spec(build_model(cfg).init_abstract())
@@ -59,7 +60,7 @@ def test_gemma2_2b_heads_replicated():
 
 def test_moe_expert_parallel_spec():
     cfg = get_config("qwen3-moe-30b-a3b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     rules = ShardingRules(cfg, mesh, mode="tp")
     rules.tp_size = 16
     spec = rules.params_spec(build_model(cfg).init_abstract())
@@ -69,7 +70,7 @@ def test_moe_expert_parallel_spec():
 
 def test_fsdp_adds_data_axis():
     cfg = get_config("llama4-maverick-400b-a17b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     rules = ShardingRules(cfg, mesh, mode="fsdp")
     rules.tp_size = 16
     rules.dp_size = 16
@@ -79,7 +80,7 @@ def test_fsdp_adds_data_axis():
 
 
 def test_choose_mode_policy():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
 
     class FakeShape(dict):
         pass
@@ -106,6 +107,7 @@ def test_multidevice_sharded_step_runs():
         from repro.train.step import make_train_step
         from repro.data import DataConfig
         from repro.data.pipeline import batch_at
+        from repro.launch.mesh import auto_mesh
 
         cfg = smoke_config("qwen3-moe-30b-a3b").with_overrides(
             dtype="float32")
@@ -121,7 +123,7 @@ def test_multidevice_sharded_step_runs():
         # single device reference
         l_ref = jax.jit(step)(params, opt, batch)[2]["loss"]
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = auto_mesh((4, 2), ("data", "model"))
         rules = ShardingRules(cfg, mesh, mode="tp")
         psh = rules.to_sharding(rules.params_spec(
             jax.eval_shape(lambda: params)))

@@ -143,16 +143,28 @@ def test_compile_plan_dispatches_by_kind():
     assert moe.stats()["kind"] == "moe"
 
 
-def test_compiled_moe_bucketing_and_chunking():
+# many experts at a tight capacity factor: expert capacity binds, so a
+# block that shared its routing with its batch would lose tokens to it
+BINDING_CAPACITY = MoEWorkloadSpec(
+    layers=(MoELayerSpec(d_ff_expert=16, num_experts=16, top_k=4,
+                         capacity_factor=0.5),), d_model=8, seq_len=8)
+
+
+@pytest.mark.parametrize("spec", [tiny_moe_spec(), BINDING_CAPACITY],
+                         ids=["tiny", "binding_capacity"])
+def test_compiled_moe_bucketing_and_chunking(spec):
     """Padding to a bucket and chunking past max_batch must not change
-    any request's output (the CompiledCNN contract, on the MoE backend:
-    padding tokens can never displace real tokens under capacity)."""
-    plan = plan_moe_deployment(tiny_moe_spec(), "v5e")
+    any request's output (the CompiledCNN contract, on the MoE backend):
+    each token block is routed on its own, so neither padding nor the
+    other blocks of a dispatch compete for its expert capacity."""
+    plan = plan_moe_deployment(spec, "v5e")
     compiled = compile_plan(plan, max_batch=4)
     xs = np.stack(compiled.sample_inputs(7, seed=3))
     y_all = np.asarray(compiled(xs))        # chunks 4 + 3(pad to 4)
     singles = np.stack([np.asarray(compiled(x)) for x in xs])
     np.testing.assert_allclose(y_all, singles, rtol=1e-5, atol=1e-5)
+    eager = np.asarray(_eager_forward(compiled.spec, compiled.params, xs))
+    np.testing.assert_allclose(y_all, eager, rtol=1e-5, atol=1e-5)
     assert sum(compiled.bucket_hits.values()) > 0
 
 
