@@ -296,8 +296,9 @@ def run_moe_async(args) -> None:
                                max_pending=args.max_pending,
                                max_inflight=args.max_inflight),
         plan_id="moe", exec_cache=cache, tracker=tracker)
-    sampler = _ops_sampler(tracker, {"gateway": gw.stats})
     compiled = gw.plans["moe"].compiled
+    sampler = _ops_sampler(tracker, {"gateway": gw.stats,
+                                     "executor": compiled.stats})
     print(f"[serve] AOT warmup: {len(compiled.buckets)} buckets × "
           f"{compiled.num_layers} MoE layers in {time.time() - t0:.2f}s")
 
@@ -407,8 +408,9 @@ def run_cnn_async(args) -> None:
                                max_inflight=args.max_inflight,
                                wait_budget_s=wait_budget),
         mesh=mesh, exec_cache=cache, tracker=tracker)
-    sampler = _ops_sampler(tracker, {"gateway": gw.stats})
     compiled = gw.plans["plan0"].compiled
+    sampler = _ops_sampler(tracker, {"gateway": gw.stats,
+                                     "executor": compiled.stats})
     print(f"[serve] AOT warmup: {len(compiled.buckets)} buckets × "
           f"{len(compiled.cfg.layers)} layers compiled in "
           f"{time.time() - t0:.2f}s (shared exec cache: "

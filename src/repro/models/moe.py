@@ -100,62 +100,66 @@ def _moe_layer_flat(p, x, cfg):
     e, k = m.num_experts, m.top_k
     xf = x.reshape(n, d)
 
-    router_logits = xf.astype(jnp.float32) @ p["router"]          # (N,E)
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    top_vals, top_ids = _top_k(probs, k)                          # (N,k)
-    top_vals = top_vals / jnp.clip(
-        jnp.sum(top_vals, axis=-1, keepdims=True), 1e-9)          # renorm
+    with jax.named_scope("router"):
+        router_logits = xf.astype(jnp.float32) @ p["router"]      # (N,E)
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        top_vals, top_ids = _top_k(probs, k)                      # (N,k)
+        top_vals = top_vals / jnp.clip(
+            jnp.sum(top_vals, axis=-1, keepdims=True), 1e-9)      # renorm
 
-    # ---- load-balancing auxiliary loss (switch-style) ----------------
-    me = jnp.mean(probs, axis=0)                                  # (E,)
-    ce = jnp.mean(
-        jnp.sum(jax.nn.one_hot(top_ids, e, dtype=jnp.float32), axis=1),
-        axis=0)
-    aux = e * jnp.sum(me * ce) * m.router_aux_weight
+        # ---- load-balancing auxiliary loss (switch-style) ------------
+        me = jnp.mean(probs, axis=0)                              # (E,)
+        ce = jnp.mean(
+            jnp.sum(jax.nn.one_hot(top_ids, e, dtype=jnp.float32),
+                    axis=1), axis=0)
+        aux = e * jnp.sum(me * ce) * m.router_aux_weight
 
-    # ---- sort-based rank-within-expert -------------------------------
-    capacity = int(max(k, round(m.capacity_factor * n * k / e)))
-    flat_ids = top_ids.reshape(-1)                                # (N*k,)
-    sort_idx = jnp.argsort(flat_ids)                              # stable
-    sorted_ids = flat_ids[sort_idx]
-    counts = jnp.bincount(flat_ids, length=e)                     # (E,)
-    starts = jnp.cumsum(counts) - counts                          # exclusive
-    ranks_sorted = jnp.arange(n * k) - starts[sorted_ids]
-    ranks = jnp.zeros_like(ranks_sorted).at[sort_idx].set(ranks_sorted)
-
-    keep = ranks < capacity
-    slot = jnp.where(keep, flat_ids * capacity + ranks, e * capacity)
-
-    # ---- dispatch: scatter tokens into the expert buffer -------------
-    token_of = jnp.repeat(jnp.arange(n), k)                       # (N*k,)
     hints = cfg.moe_shard_hints
-    buf = jnp.zeros((e * capacity + 1, d), x.dtype)
-    buf = buf.at[slot].set(xf[token_of], mode="drop")
-    expert_in = _hint(buf[:-1].reshape(e, capacity, d),
-                      ("model", "data", None), hints)
+    with jax.named_scope("dispatch"):
+        # ---- sort-based rank-within-expert ---------------------------
+        capacity = int(max(k, round(m.capacity_factor * n * k / e)))
+        flat_ids = top_ids.reshape(-1)                            # (N*k,)
+        sort_idx = jnp.argsort(flat_ids)                          # stable
+        sorted_ids = flat_ids[sort_idx]
+        counts = jnp.bincount(flat_ids, length=e)                 # (E,)
+        starts = jnp.cumsum(counts) - counts                      # exclusive
+        ranks_sorted = jnp.arange(n * k) - starts[sorted_ids]
+        ranks = jnp.zeros_like(ranks_sorted).at[sort_idx].set(ranks_sorted)
 
-    # ---- expert FFN (batched over experts) ----------------------------
-    h = jnp.einsum("ecd,edf->ecf", expert_in, p["w_up"])
-    if "w_gate" in p:
-        h = _act(jnp.einsum("ecd,edf->ecf", expert_in, p["w_gate"]),
-                 cfg.act) * h
-    else:
-        h = _act(h, cfg.act)
-    h = _hint(h, ("model", "data", None), hints)
-    expert_out = _hint(jnp.einsum("ecf,efd->ecd", h, p["w_down"]),
-                       ("model", "data", None), hints)
+        keep = ranks < capacity
+        slot = jnp.where(keep, flat_ids * capacity + ranks, e * capacity)
 
-    # ---- combine: gather surviving assignments back -------------------
-    flat_out = expert_out.reshape(e * capacity, d)
-    gathered = jnp.where(
-        keep[:, None], flat_out[jnp.minimum(slot, e * capacity - 1)],
-        jnp.zeros((), x.dtype))                                    # (N*k, D)
-    gathered = _hint(gathered, ("data", None), hints)
-    # fused f32 contraction over k — never materializes an f32 (N·k, D)
-    out = jnp.einsum("nkd,nk->nd", gathered.reshape(n, k, d),
-                     top_vals.astype(jnp.float32),
-                     preferred_element_type=jnp.float32).astype(x.dtype)
-    out = _hint(out, ("data", None), hints)
+        # ---- scatter tokens into the expert buffer -------------------
+        token_of = jnp.repeat(jnp.arange(n), k)                   # (N*k,)
+        buf = jnp.zeros((e * capacity + 1, d), x.dtype)
+        buf = buf.at[slot].set(xf[token_of], mode="drop")
+        expert_in = _hint(buf[:-1].reshape(e, capacity, d),
+                          ("model", "data", None), hints)
+
+    with jax.named_scope("expert_ffn"):
+        # batched over experts
+        h = jnp.einsum("ecd,edf->ecf", expert_in, p["w_up"])
+        if "w_gate" in p:
+            h = _act(jnp.einsum("ecd,edf->ecf", expert_in, p["w_gate"]),
+                     cfg.act) * h
+        else:
+            h = _act(h, cfg.act)
+        h = _hint(h, ("model", "data", None), hints)
+        expert_out = _hint(jnp.einsum("ecf,efd->ecd", h, p["w_down"]),
+                           ("model", "data", None), hints)
+
+    with jax.named_scope("combine"):
+        # gather surviving assignments back
+        flat_out = expert_out.reshape(e * capacity, d)
+        gathered = jnp.where(
+            keep[:, None], flat_out[jnp.minimum(slot, e * capacity - 1)],
+            jnp.zeros((), x.dtype))                                # (N*k, D)
+        gathered = _hint(gathered, ("data", None), hints)
+        # fused f32 contraction over k — never materializes an f32 (N·k, D)
+        out = jnp.einsum("nkd,nk->nd", gathered.reshape(n, k, d),
+                         top_vals.astype(jnp.float32),
+                         preferred_element_type=jnp.float32).astype(x.dtype)
+        out = _hint(out, ("data", None), hints)
 
     # ---- shared experts (always-on path) ------------------------------
     if "shared_up" in p:
@@ -193,16 +197,18 @@ def moe_layer_grouped(p, x, cfg):
     hints = cfg.moe_shard_hints
     xg = _hint(x.reshape(g, nl, d), ("data", None, None), hints)
 
-    router_logits = xg.astype(jnp.float32) @ p["router"]          # (G,NL,E)
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    top_vals, top_ids = _top_k(probs, k)                          # (G,NL,k)
-    top_vals = top_vals / jnp.clip(
-        jnp.sum(top_vals, axis=-1, keepdims=True), 1e-9)
+    with jax.named_scope("router"):
+        router_logits = xg.astype(jnp.float32) @ p["router"]      # (G,NL,E)
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        top_vals, top_ids = _top_k(probs, k)                      # (G,NL,k)
+        top_vals = top_vals / jnp.clip(
+            jnp.sum(top_vals, axis=-1, keepdims=True), 1e-9)
 
-    me = jnp.mean(probs, axis=(0, 1))
-    ce = jnp.mean(jnp.sum(jax.nn.one_hot(top_ids, e, dtype=jnp.float32),
-                          axis=2), axis=(0, 1))
-    aux = e * jnp.sum(me * ce) * m.router_aux_weight
+        me = jnp.mean(probs, axis=(0, 1))
+        ce = jnp.mean(jnp.sum(jax.nn.one_hot(top_ids, e,
+                                             dtype=jnp.float32),
+                              axis=2), axis=(0, 1))
+        aux = e * jnp.sum(me * ce) * m.router_aux_weight
 
     cap = int(max(k, round(m.capacity_factor * nl * k / e)))
 
@@ -224,25 +230,27 @@ def moe_layer_grouped(p, x, cfg):
         buf = buf.at[slot_g].set(xl[token_of], mode="drop")
         return buf[:-1].reshape(e, cap, d)
 
-    slot, keep = jax.vmap(rank_group)(top_ids)
-    if cfg.moe_combine_shardmap:
-        # per model rank, build ONLY the local experts' buffers — the
-        # forward dispatch needs no collective at all (§Perf B6)
-        expert_in = _dispatch_shardmap(xg, slot, keep, nl=nl, e=e,
-                                       cap=cap, d=d, k=k)
-    else:
-        expert_in = jax.vmap(build_buf)(xg, slot, keep)
-    expert_in = _hint(expert_in, ("data", "model", None, None), hints)
+    with jax.named_scope("dispatch"):
+        slot, keep = jax.vmap(rank_group)(top_ids)
+        if cfg.moe_combine_shardmap:
+            # per model rank, build ONLY the local experts' buffers — the
+            # forward dispatch needs no collective at all (§Perf B6)
+            expert_in = _dispatch_shardmap(xg, slot, keep, nl=nl, e=e,
+                                           cap=cap, d=d, k=k)
+        else:
+            expert_in = jax.vmap(build_buf)(xg, slot, keep)
+        expert_in = _hint(expert_in, ("data", "model", None, None), hints)
 
-    h = jnp.einsum("gecd,edf->gecf", expert_in, p["w_up"])
-    if "w_gate" in p:
-        h = _act(jnp.einsum("gecd,edf->gecf", expert_in, p["w_gate"]),
-                 cfg.act) * h
-    else:
-        h = _act(h, cfg.act)
-    h = _hint(h, ("data", "model", None, None), hints)
-    expert_out = _hint(jnp.einsum("gecf,efd->gecd", h, p["w_down"]),
-                       ("data", "model", None, None), hints)
+    with jax.named_scope("expert_ffn"):
+        h = jnp.einsum("gecd,edf->gecf", expert_in, p["w_up"])
+        if "w_gate" in p:
+            h = _act(jnp.einsum("gecd,edf->gecf", expert_in, p["w_gate"]),
+                     cfg.act) * h
+        else:
+            h = _act(h, cfg.act)
+        h = _hint(h, ("data", "model", None, None), hints)
+        expert_out = _hint(jnp.einsum("gecf,efd->gecd", h, p["w_down"]),
+                           ("data", "model", None, None), hints)
 
     def combine_group(outs, slot_g, keep_g, vals):
         # scatter-add combine: weighted contributions accumulate straight
@@ -257,13 +265,14 @@ def moe_layer_grouped(p, x, cfg):
         acc = acc.at[idx].add(contrib.astype(jnp.float32), mode="drop")
         return acc[:-1]
 
-    if cfg.moe_combine_shardmap:
-        out = _combine_shardmap(expert_out, slot, keep, top_vals,
-                                nl=nl, e=e, cap=cap, d=d, k=k)
-    else:
-        out = jax.vmap(combine_group)(expert_out, slot, keep, top_vals)
-    out = _hint(out.astype(x.dtype), ("data", None, None), hints)
-    out = out.reshape(b, s, d)
+    with jax.named_scope("combine"):
+        if cfg.moe_combine_shardmap:
+            out = _combine_shardmap(expert_out, slot, keep, top_vals,
+                                    nl=nl, e=e, cap=cap, d=d, k=k)
+        else:
+            out = jax.vmap(combine_group)(expert_out, slot, keep, top_vals)
+        out = _hint(out.astype(x.dtype), ("data", None, None), hints)
+        out = out.reshape(b, s, d)
 
     if "shared_up" in p:
         xf = x.reshape(n, d)

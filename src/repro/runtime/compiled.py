@@ -182,6 +182,69 @@ class ExecutableCache:
                     "coalesced": self.coalesced}
 
 
+class _Span:
+    """One open span of a ``SpanTotals`` (see ``SpanTotals.span``)."""
+
+    __slots__ = ("_totals", "_name", "_trace", "_t0")
+
+    def __init__(self, totals: "SpanTotals", name: str, trace):
+        self._totals, self._name, self._trace = totals, name, trace
+
+    def __enter__(self) -> "_Span":
+        self._trace.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        seconds = time.perf_counter() - self._t0
+        self._trace.__exit__(*exc)
+        self._totals.add(self._name, seconds)
+
+
+class SpanTotals:
+    """Host time spent inside named spans, and how many there were.
+
+    ``with totals.span("gateway.submit", request_id=7):`` times its body
+    on ``time.perf_counter`` and opens a ``jax.profiler.TraceAnnotation``
+    named ``repro.gateway.submit``: a TraceMe on the profiler's own
+    clock, so a traced run shows the span on the device's timeline.
+    Names are static strings and keyword arguments ints, so nothing is
+    formatted while the profiler is off.  ``add`` counts time that no
+    one thread's span can hold (a hop between threads).
+
+    ``snapshot()`` is ``{name: (count, seconds)}``.  Every update takes
+    one lock, so the event loop and the dispatch worker never lose each
+    other's counts."""
+
+    TRACE_PREFIX = "repro."
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._totals: Dict[str, list] = {}
+        self._trace_names: Dict[str, str] = {}
+
+    def span(self, name: str, **ids: int) -> _Span:
+        trace_name = self._trace_names.get(name)
+        if trace_name is None:
+            trace_name = self._trace_names.setdefault(
+                name, self.TRACE_PREFIX + name)
+        return _Span(self, name, jax.profiler.TraceAnnotation(trace_name,
+                                                              **ids))
+
+    def add(self, name: str, seconds: float) -> None:
+        with self._lock:
+            entry = self._totals.get(name)
+            if entry is None:
+                self._totals[name] = [1, seconds]
+            else:
+                entry[0] += 1
+                entry[1] += seconds
+
+    def snapshot(self) -> Dict[str, Tuple[int, float]]:
+        with self._lock:
+            return {k: (c, s) for k, (c, s) in self._totals.items()}
+
+
 def bucket_ladder(max_batch: int) -> Tuple[int, ...]:
     """Power-of-two batch buckets up to ``max_batch`` (which is always
     the top rung, even when it is not itself a power of two)."""
@@ -273,6 +336,9 @@ class CompiledModel:
         self.compiles = 0              # compiles this instance performed
         self.bucket_hits: Dict[int, int] = {b: 0 for b in self.buckets}
         self.calls = 0
+        self.rows = 0                  # rows the buckets ran, padding too
+        self.padded_rows = 0           # of which padding
+        self.spans = SpanTotals()      # executor.* host time
         self._stats_lock = threading.Lock()
         if warmup:
             self.warmup()
@@ -358,8 +424,9 @@ class CompiledModel:
         n = xb.shape[0]
         bucket = self.bucket_for(n)
         if n < bucket:
-            pad = jnp.zeros((bucket - n,) + xb.shape[1:], xb.dtype)
-            xb = jnp.concatenate([xb, pad])
+            with self.spans.span("executor.pad"):
+                pad = jnp.zeros((bucket - n,) + xb.shape[1:], xb.dtype)
+                xb = jnp.concatenate([xb, pad])
         xb = self._place_batch(xb, bucket)
         act = xb
         for i in range(self.num_layers):
@@ -367,10 +434,14 @@ class CompiledModel:
                 raise DispatchAborted(
                     f"dispatch abandoned before layer {i} "
                     f"(all served requests cancelled)")
-            act = self._compile_layer(i, bucket)(
-                self._layer_params(i), act)
+            # dispatch is asynchronous: this times the host's launch
+            with self.spans.span("executor.launch", layer=i):
+                act = self._compile_layer(i, bucket)(
+                    self._layer_params(i), act)
         with self._stats_lock:
             self.bucket_hits[bucket] += 1
+            self.rows += bucket
+            self.padded_rows += bucket - n
         return act[:n]
 
     def __call__(self, x, *, should_abort=None):
@@ -382,7 +453,8 @@ class CompiledModel:
         layers; returning True raises ``DispatchAborted`` — the async
         gateway's cancellation hook, so a flight whose every request was
         cancelled mid-execution stops paying for the remaining layers."""
-        x = jnp.asarray(x)
+        with self.spans.span("executor.h2d"):
+            x = jnp.asarray(x)
         single = x.ndim == len(self.in_shape)
         if single:
             x = x[None]
@@ -408,12 +480,16 @@ class CompiledModel:
         """Dispatch + compile telemetry.  ``executables``/``cache_*``
         describe the (possibly shared) ``ExecutableCache``; ``compiles``
         counts builds *this instance* performed — with a shared cache,
-        a second plan over identical layers reports 0.  Snapshot is
-        lock-consistent under the async drain."""
+        a second plan over identical layers reports 0.  ``rows`` and
+        ``padded_rows`` count the rows the buckets ran and how many of
+        them were padding; ``spans`` is the executor's host time per
+        span (``SpanTotals.snapshot``).  Snapshot is lock-consistent
+        under the async drain."""
         with self._stats_lock:
             hits = dict(self.bucket_hits)
             calls = self.calls
             compiles = self.compiles
+            rows, padded_rows = self.rows, self.padded_rows
         cache = self.cache.stats()
         return {
             "kind": self.kind,
@@ -424,6 +500,9 @@ class CompiledModel:
             "cache_compiles": cache["compiles"],
             "cache_hits": cache["hits"],
             "calls": calls,
+            "rows": rows,
+            "padded_rows": padded_rows,
+            "spans": self.spans.snapshot(),
             "warmed_up": self.warmed_up,
         }
 
@@ -489,7 +568,15 @@ class CompiledCNN(CompiledModel):
                 self.cfg.img_h, self.cfg.img_w, self._mesh_token, bucket)
 
     def _layer_fn(self, i: int):
-        return cnn_layer(self.cfg.layers[i], self.blocks[i], self.mesh)
+        spec, block = self.cfg.layers[i], self.blocks[i]
+        fn = cnn_layer(spec, block, self.mesh)
+        # the executable's name in a trace, e.g. jit_cnn_conv3_d8c8_64to128:
+        # from the layer's content, as the cache key is, since identical
+        # layers share one executable
+        fn.__name__ = (f"cnn_{block.name}_d{spec.data_bits}"
+                       f"c{spec.coeff_bits}_{spec.in_channels}to"
+                       f"{spec.out_channels}")
+        return fn
 
     def _layer_params(self, i: int):
         return self.params[i]

@@ -445,6 +445,12 @@ class CompiledMoE(CompiledModel):
             # residual MoE block over the quantized activation grid
             return x + _route_per_block(p, _fake_quant(x, data_bits), cfg)
 
+        # the executable's name in a trace, e.g. jit_moe_e128_k8_d4c4:
+        # from the layer's content, as the cache key is, since identical
+        # layers share one executable
+        s = self.spec.layers[i]
+        layer.__name__ = (f"moe_e{s.num_experts}_k{s.top_k}"
+                          f"_d{s.data_bits}c{s.coeff_bits}")
         return layer
 
     def _layer_params(self, i: int):

@@ -51,6 +51,13 @@ vLLM-style request-level scheduler adapted to feed-forward CNN serving:
                 compiling per plan.  Each batch is single-plan (plans
                 may differ in geometry/precision); the scheduler picks
                 the plan owning the most urgent pending request.
+  spans         host time at each boundary (``runtime.SpanTotals``,
+                each span also a profiler TraceMe ``repro.<name>``):
+                ``gateway.submit``, ``.form_batch``, ``.stack`` and
+                ``.resolve`` on the event loop, ``gateway.handoff`` to
+                the worker and back, the worker's
+                ``executor.device_wait``/``.d2h``; with ``launched``
+                and ``queue_wait_s`` in every ``GatewayStats``.
 
 The scheduling core (``AdmissionQueue``) is deliberately synchronous
 and clock-injected — the admission-bound and deadline invariants are
@@ -68,10 +75,11 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.runtime.compiled import (CompiledModel, DispatchAborted,
-                                    ExecutableCache)
+                                    ExecutableCache, SpanTotals)
 from repro.serve import policy as policy_mod
 from repro.serve.policy import PolicyLike, get_policy
 from repro.serve.slots import GatewayStats, SlotPool
@@ -452,6 +460,7 @@ class AsyncCNNGateway(SlotPool):
         self._closing = False
         self._inflight = 0             # dispatches currently launched
         self._next_id = 0
+        self._dispatch_seq = 0         # batches formed so far
         self._last_adapt = -math.inf   # rate-limits per-arrival resizes
         # counters (all mutated on the loop thread; read anywhere)
         self.served = 0
@@ -459,6 +468,11 @@ class AsyncCNNGateway(SlotPool):
         self.cancelled = 0
         self.failed = 0
         self.aborted_dispatches = 0
+        # requests launched, and their summed wait in the queue (arrival
+        # to launch on ``clock``); host time of the gateway.* spans
+        self.launched = 0
+        self.queue_wait_s = 0.0
+        self.spans = SpanTotals()
 
     # -- plan registry ----------------------------------------------------
     def register_plan(self, plan, *, plan_id: Optional[str] = None,
@@ -710,32 +724,34 @@ class AsyncCNNGateway(SlotPool):
         self._ensure_started()
         if self._closing:
             raise RuntimeError("gateway is closing")
-        self._adapt_bound()
-        if self.queue.full:
-            # refuse *before* building the request: under sustained
-            # overload the refused path is the hot path, and paying
-            # image validation + future wiring per shed arrival steals
-            # event-loop time from dispatch
+        with self.spans.span("gateway.submit", request_id=self._next_id):
+            self._adapt_bound()
+            if self.queue.full:
+                # refuse *before* building the request: under sustained
+                # overload the refused path is the hot path, and paying
+                # image validation + future wiring per shed arrival
+                # steals event-loop time from dispatch
+                now = self.clock()
+                probe = _ShedProbe(
+                    priority, None if deadline is None else now + deadline)
+                if not self.queue.outranked_by(probe, now):
+                    self.rejected += 1
+                    raise GatewayBacklog(
+                        f"pending queue at its bound "
+                        f"({self.queue.max_pending}); retry with backoff "
+                        f"or use `await submit(...)` for backpressure")
+            req, fut = self._make_request(image, plan_id, priority,
+                                          deadline)
             now = self.clock()
-            probe = _ShedProbe(
-                priority, None if deadline is None else now + deadline)
-            if not self.queue.outranked_by(probe, now):
-                self.rejected += 1
-                raise GatewayBacklog(
-                    f"pending queue at its bound "
-                    f"({self.queue.max_pending}); retry with backoff or "
-                    f"use `await submit(...)` for backpressure")
-        req, fut = self._make_request(image, plan_id, priority, deadline)
-        now = self.clock()
-        if not self.queue.admit(req, now):
-            victim = self.queue.shed_victim(req, now)
-            if victim is None or not self.queue.admit(req, now):
-                self.rejected += 1
-                raise GatewayBacklog(
-                    f"pending queue at its bound "
-                    f"({self.queue.max_pending}); retry with backoff or "
-                    f"use `await submit(...)` for backpressure")
-        self._bookkeep_admitted(req)
+            if not self.queue.admit(req, now):
+                victim = self.queue.shed_victim(req, now)
+                if victim is None or not self.queue.admit(req, now):
+                    self.rejected += 1
+                    raise GatewayBacklog(
+                        f"pending queue at its bound "
+                        f"({self.queue.max_pending}); retry with backoff "
+                        f"or use `await submit(...)` for backpressure")
+            self._bookkeep_admitted(req)
         return fut
 
     def submit_chunk(self, images, *, plan_id: Optional[str] = None,
@@ -770,7 +786,19 @@ class AsyncCNNGateway(SlotPool):
         self._ensure_started()
         if self._closing:
             raise RuntimeError("gateway is closing")
-        req, fut = self._make_request(image, plan_id, priority, deadline)
+        # the span holds the synchronous part only, never a wait
+        with self.spans.span("gateway.submit", request_id=self._next_id):
+            req, fut = self._make_request(image, plan_id, priority,
+                                          deadline)
+            settled = self._try_admit(req)
+        while not settled:
+            await self._space.wait()
+            settled = self._try_admit(req)
+        return fut
+
+    def _try_admit(self, req: AsyncRequest) -> bool:
+        """One admission attempt of ``submit``: True when ``req`` is
+        queued or finished, False when the caller must await space."""
         while True:
             if self._closing:
                 # a wakeup from close() must *not* re-try admission:
@@ -781,7 +809,7 @@ class AsyncCNNGateway(SlotPool):
                     self.failed += 1
                     req._finish("failed",
                                 error=RuntimeError("gateway is closing"))
-                return fut
+                return True
             if req.plan_id in self._retiring \
                     or req.plan_id not in self.plans:
                 # the target plan retired while this submit awaited
@@ -792,15 +820,14 @@ class AsyncCNNGateway(SlotPool):
                     req._finish("failed", error=PlanUnavailable(
                         f"plan {req.plan_id!r} retired while awaiting "
                         f"admission"))
-                return fut
+                return True
             self._adapt_bound()
             if self.queue.admit(req, self.clock()):
                 self._bookkeep_admitted(req)
-                return fut
+                return True
             self._space.clear()
-            if not self.queue.full:   # space freed before the clear —
-                continue              # re-check avoids a lost wakeup
-            await self._space.wait()
+            if self.queue.full:       # space freed before the clear —
+                return False          # re-check avoids a lost wakeup
 
     def _bookkeep_admitted(self, req: AsyncRequest) -> None:
         if req.status == "pending":
@@ -878,8 +905,13 @@ class AsyncCNNGateway(SlotPool):
                 # not the pool size — the pool is max_inflight batches
                 # wide so the next batch stages while one is on-device
                 width = min(free, self.cfg.max_batch)
-                plan_id, batch = self.queue.pop_batch(width, self.clock())
-                self._signal_space()
+                seq = self._dispatch_seq
+                with self.spans.span("gateway.form_batch", dispatch=seq):
+                    plan_id, batch = self.queue.pop_batch(width,
+                                                          self.clock())
+                    self._signal_space()
+                    if batch and plan_id in self.plans:
+                        slots = [self.occupy(r) for r in batch]
                 if batch and plan_id not in self.plans:
                     # the plan was evicted with requests still queued
                     # (shouldn't happen — retire drains first — but a
@@ -890,10 +922,10 @@ class AsyncCNNGateway(SlotPool):
                             f"plan {plan_id!r} is no longer registered"))
                     continue
                 if batch:
-                    slots = [self.occupy(r) for r in batch]
+                    self._dispatch_seq += 1
                     self._inflight += 1
                     flight = loop.create_task(self._run_batch(
-                        self.plans[plan_id], batch, slots))
+                        self.plans[plan_id], batch, slots, seq))
                     pending_flights.add(flight)
                     flight.add_done_callback(pending_flights.discard)
                     launched = True
@@ -904,17 +936,40 @@ class AsyncCNNGateway(SlotPool):
                 return
             await self._wake.wait()
 
-    async def _run_batch(self, entry: _PlanEntry, batch, slots) -> None:
+    async def _run_batch(self, entry: _PlanEntry, batch, slots,
+                         seq: int) -> None:
         compiled = entry.compiled
         launched_at = self._rate_clock()
         alive = [r for r in batch if r.status == "pending"]
+        self.launched += len(alive)
+        self.queue_wait_s += sum(launched_at - r.arrived_at for r in alive)
+        out = None
         try:
             if alive:
-                images = np.stack([np.asarray(r.image, compiled.in_dtype)
-                                   for r in alive])
+                with self.spans.span("gateway.stack", dispatch=seq):
+                    images = np.stack([np.asarray(r.image,
+                                                  compiled.in_dtype)
+                                       for r in alive])
 
                 def abort() -> bool:
                     return all(r.status != "pending" for r in alive)
+
+                hop = [0.0, 0.0]      # the worker's first and last line
+                spans = self.spans
+
+                def work():
+                    hop[0] = time.perf_counter()
+                    try:
+                        y = compiled(images, should_abort=abort)
+                        # queue the copy back behind the computation, as
+                        # np.asarray alone does, before waiting for it
+                        jax.copy_to_host_async(y)
+                        with spans.span("executor.device_wait"):
+                            y = jax.block_until_ready(y)
+                        with spans.span("executor.d2h"):
+                            return np.asarray(y)
+                    finally:
+                        hop[1] = time.perf_counter()
 
                 try:
                     # chaos seam: a scheduled worker crash raises here
@@ -923,10 +978,12 @@ class AsyncCNNGateway(SlotPool):
                     # and re-routes, exactly as for a real device loss
                     self._fault_check("dispatch", plan_id=entry.plan_id,
                                       n=len(alive))
-                    out = await self._loop.run_in_executor(
-                        self._executor,
-                        lambda: np.asarray(
-                            compiled(images, should_abort=abort)))
+                    sent = time.perf_counter()
+                    out = await self._loop.run_in_executor(self._executor,
+                                                           work)
+                    self.spans.add("gateway.handoff",
+                                   hop[0] - sent
+                                   + time.perf_counter() - hop[1])
                 except DispatchAborted:
                     self.aborted_dispatches += 1
                     self._track("dispatch_aborted",
@@ -939,23 +996,27 @@ class AsyncCNNGateway(SlotPool):
                         r._finish("failed", error=e)
                         self.failed += 1
                     out = None
-                if out is not None:
-                    done = 0
-                    for k, r in enumerate(alive):
-                        if r.status == "pending":
-                            r._finish("done", output=out[k])
-                            self.served += 1
-                            entry.served += 1
-                            done += 1
-                    self._note_step(len(alive), launched_at=launched_at)
-                    self._track("dispatch_complete",
-                                plan_id=entry.plan_id, n=done)
         finally:
-            self._inflight -= 1
-            for s in slots:
-                self.release(s)       # hooks re-wake the drain task
-            self._adapt_bound(force=True)   # fresh rate → fresh bound
-            self._signal_space()
+            with self.spans.span("gateway.resolve", dispatch=seq):
+                try:
+                    if out is not None:
+                        done = 0
+                        for k, r in enumerate(alive):
+                            if r.status == "pending":
+                                r._finish("done", output=out[k])
+                                self.served += 1
+                                entry.served += 1
+                                done += 1
+                        self._note_step(len(alive),
+                                        launched_at=launched_at)
+                        self._track("dispatch_complete",
+                                    plan_id=entry.plan_id, n=done)
+                finally:
+                    self._inflight -= 1
+                    for s in slots:
+                        self.release(s)   # hooks re-wake the drain task
+                    self._adapt_bound(force=True)  # fresh rate → bound
+                    self._signal_space()
 
     # -- fleet draining seam ----------------------------------------------
     def extract_queued(self) -> List[AsyncRequest]:
@@ -1007,7 +1068,8 @@ class AsyncCNNGateway(SlotPool):
             clock=self.clock, queue_depth=len(self.queue),
             served=self.served, rejected=self.rejected,
             expired=self.queue.expired, cancelled=self.cancelled,
-            failed=self.failed)
+            failed=self.failed, launched=self.launched,
+            queue_wait_s=self.queue_wait_s, spans=self.spans.snapshot())
 
     def stats(self) -> dict:
         """Gateway counters + the SlotPool occupancy histogram + the
@@ -1038,5 +1100,8 @@ class AsyncCNNGateway(SlotPool):
             "occupancy_hist": dict(snap.occupancy_hist),
             "service_rate": snap.service_rate,
             "est_wait": snap.est_wait,
+            "launched": snap.launched,
+            "queue_wait_s": snap.queue_wait_s,
+            "spans": snap.spans,
             "exec_cache": self.exec_cache.stats(),
         }

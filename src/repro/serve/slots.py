@@ -86,6 +86,12 @@ class GatewayStats:
     # routers prefer these over inferring wait from raw queue depth.
     service_rate: float = 0.0
     est_wait: float = 0.0
+    # requests launched into a dispatch, their summed queue wait
+    # (arrival → launch on the owner's clock), and the owner's host time
+    # per span, {name: (count, seconds)} (``runtime.SpanTotals``)
+    launched: int = 0
+    queue_wait_s: float = 0.0
+    spans: Dict[str, Tuple[int, float]] = field(default_factory=dict)
 
     @property
     def depth(self) -> int:
