@@ -265,20 +265,28 @@ def validate_container_input(x, in_shape, in_dtype, request_id=0, *,
     (the CNN input contract).  A float array must carry exact
     container-range integers — silent ``np.asarray(x, in_dtype)``
     truncation (0.9 → 0, 200.0 → -56 for int8) is a ``ValueError``
-    here, as is any value that would wrap in the container."""
+    here, as is any value that would wrap in the container.
+
+    An integer dtype whose whole range fits the container (int8 into
+    int8) is admitted on its dtype alone: no pass over the values.
+    Otherwise the range check is two reductions, which build no
+    array-sized temporary."""
     x = np.asarray(x)
     if tuple(x.shape) != tuple(in_shape):
         raise ValueError(
             f"request {request_id}: {noun} shape {tuple(x.shape)} "
             f"!= engine input {tuple(in_shape)}")
-    if not np.issubdtype(x.dtype, np.integer):
-        if not np.all(np.isfinite(x)) or np.any(x != np.round(x)):
-            raise ValueError(
-                f"request {request_id}: {noun} dtype {x.dtype} "
-                f"carries non-integral values — quantize explicitly "
-                f"(e.g. ops.quantize_fixed) before submitting")
     info = np.iinfo(in_dtype)
-    if np.any(x < info.min) or np.any(x > info.max):
+    if np.issubdtype(x.dtype, np.integer):
+        held = np.iinfo(x.dtype)
+        if info.min <= held.min and held.max <= info.max:
+            return x
+    elif not np.all(np.isfinite(x)) or np.any(x != np.round(x)):
+        raise ValueError(
+            f"request {request_id}: {noun} dtype {x.dtype} "
+            f"carries non-integral values — quantize explicitly "
+            f"(e.g. ops.quantize_fixed) before submitting")
+    if x.size and (int(x.min()) < info.min or int(x.max()) > info.max):
         raise ValueError(
             f"request {request_id}: {noun} values outside the "
             f"{np.dtype(in_dtype).name} container range "

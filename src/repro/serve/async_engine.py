@@ -278,7 +278,10 @@ class AdmissionQueue:
         the victim, or ``None`` when ``req`` is itself the least
         urgent (the caller refuses it — under FIFO nothing ever
         outranks a queued request, so shedding degenerates to plain
-        refusal)."""
+        refusal).  Above a bound that shrank (``resize``), one victim
+        would free no room, so no one is shed."""
+        if self._live > self.max_pending:
+            return None
         candidate = self.policy.shed_key(req, self._seq, now)
         worst_key, victim = None, None
         for _, seq, queued in self._heap:

@@ -229,6 +229,13 @@ def test_admission_queue_resize_bound():
     assert q.admit(_req(4), 0.0) and q.full    # back under the bound
     q.resize(0)
     assert q.max_pending == 1                  # clamped: never zero
+    # above a shrunk bound a higher class sheds no one: one victim
+    # would leave the queue still over the bound
+    edf = AdmissionQueue(max_pending=2, policy="edf")
+    assert edf.admit(_req(0), 0.0) and edf.admit(_req(1), 0.0)
+    edf.resize(1)
+    assert edf.shed_victim(_req(2, priority=1), 0.0) is None
+    assert edf.shed == 0 and len(edf) == 2
 
 
 if HAVE_HYPOTHESIS:

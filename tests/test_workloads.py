@@ -336,6 +336,43 @@ def test_validate_container_input_noun():
                                  np.int8, noun="patch")
 
 
+_RANGE_ERR = ("request 7: image values outside the int8 container range "
+              "[-128, 127] — would wrap, not clamp")
+_FLOAT_ERR = ("request 7: image dtype {} carries non-integral values — "
+              "quantize explicitly (e.g. ops.quantize_fixed) before "
+              "submitting")
+
+
+@pytest.mark.parametrize("x, error", [
+    (np.full((4, 4, 2), -128, np.int8), None),
+    (np.full((4, 4, 2), 200, np.uint8), _RANGE_ERR),
+    (np.full((4, 4, 2), 127, np.uint8), None),
+    (np.arange(-128, -96, dtype=np.int16).reshape(4, 4, 2), None),
+    (np.full((4, 4, 2), -129, np.int16), _RANGE_ERR),
+    (np.full((4, 4, 2), 127.0, np.float32), None),
+    (np.full((4, 4, 2), 128.0), _RANGE_ERR),
+    (np.full((4, 4, 2), 0.5, np.float32), _FLOAT_ERR.format("float32")),
+    (np.full((4, 4, 2), np.inf), _FLOAT_ERR.format("float64")),
+    (np.full((4, 4, 2), np.nan, np.float32), _FLOAT_ERR.format("float32")),
+    (np.zeros((4, 2, 4), np.int8),
+     "request 7: image shape (4, 2, 4) != engine input (4, 4, 2)"),
+], ids=["int8-in-int8", "uint8-200", "uint8-127", "int16-in-range", "int16-below",
+        "float-integral", "float-above", "float-non-integral", "inf", "nan",
+        "wrong-shape"])
+def test_validate_container_input_verdicts(x, error):
+    """One verdict per input: accepted inputs come back with their
+    values, rejected ones raise the admission error word for word."""
+    if error is None:
+        out = validate_container_input(x, (4, 4, 2), np.int8, 7,
+                                       noun="image")
+        np.testing.assert_array_equal(out, x)
+        assert out.dtype == x.dtype
+    else:
+        with pytest.raises(ValueError) as e:
+            validate_container_input(x, (4, 4, 2), np.int8, 7, noun="image")
+        assert str(e.value) == error
+
+
 def test_validate_image_shim_keeps_the_image_noun_and_request_id():
     """The legacy name must keep producing legacy-shaped errors: the
     noun is ``image`` (not the generic ``input``) and the request id
