@@ -44,8 +44,19 @@ class MoEConfig:
     top_k: int
     d_ff_expert: int
     n_shared_experts: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: Optional[float] = 1.25   # None: dropless
     router_aux_weight: float = 0.01
+    # routing (``models.moe.route``): the weights are renormalized over
+    # the k; the defaults are a softmax top-k router
+    scoring: str = "softmax"          # "softmax" | "sigmoid" (+ bias)
+    n_group: int = 1                  # expert groups (group-limited top-k)
+    topk_group: int = 1               # groups a token may choose from
+    routed_scaling_factor: float = 1.0
+    # the experts this device holds: ``experts_held`` of them (None: all
+    # ``num_experts``) from id ``expert_offset``; the router stays
+    # ``num_experts`` wide
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
 
 
 @dataclass(frozen=True)

@@ -8,6 +8,17 @@ Tokens beyond capacity are dropped (standard switch-style routing); the
 combine step re-weights by the router probability and sums the surviving
 top-k paths.
 
+A layer may hold a share of its experts (``experts_held`` of them from
+``expert_offset``, as one device does under expert parallelism): the
+router still scores every expert and ranks are computed over all of
+them, but the buffer has rows only for the held experts, and what the
+others would add is left out of the sum (their weights still count in
+the normalization).  ``route`` also has DeepSeek-V3's sigmoid,
+group-limited router with its correction bias and routed scaling.  A
+layer with no capacity (``capacity_factor`` None) drops nothing: its
+held assignments are sorted by expert and run tile by tile
+(``_moe_layer_dropless``), as many tiles as they fill.
+
 Expert parallelism: the expert axis of w_up/w_gate/w_down is sharded over
 the ``model`` mesh axis (see parallel/sharding.py); the scatter/gather pair
 is GSPMD's to schedule in the baseline, and is replaced by an explicit
@@ -25,15 +36,21 @@ from repro.models.layers import _act, dense_init, split_keys
 def init_moe(key, cfg):
     m = cfg.moe
     d, fe, e = cfg.d_model, m.d_ff_expert, m.num_experts
+    held = held_range(m)[1]
     dt = cfg.jnp_dtype
     ks = split_keys(key, 7)
     p = {
         "router": dense_init(ks[0], (d, e), jnp.float32),
-        "w_up": dense_init(ks[1], (e, d, fe), dt, fan_in=d),
-        "w_down": dense_init(ks[2], (e, fe, d), dt, fan_in=fe),
+        "w_up": dense_init(ks[1], (held, d, fe), dt, fan_in=d),
+        "w_down": dense_init(ks[2], (held, fe, d), dt, fan_in=fe),
     }
+    if m.scoring == "sigmoid":
+        # stands in for a trained model's load-balancing correction:
+        # drawn, not zero, so that it moves the choice
+        p["router_bias"] = 0.05 * jax.random.normal(
+            jax.random.fold_in(key, 7), (e,), jnp.float32)
     if cfg.mlp_gated:
-        p["w_gate"] = dense_init(ks[3], (e, d, fe), dt, fan_in=d)
+        p["w_gate"] = dense_init(ks[3], (held, d, fe), dt, fan_in=d)
     if m.n_shared_experts:
         fs = fe * m.n_shared_experts
         p["shared_up"] = dense_init(ks[4], (d, fs), dt)
@@ -60,12 +77,69 @@ def quantize_moe_params(p, coeff_bits: int):
         s = hi / jnp.maximum(jnp.max(jnp.abs(w)), 1e-9)
         return (jnp.round(w * s) / s).astype(w.dtype)
 
-    return {k: (v if k == "router" else q(v)) for k, v in p.items()}
+    return {k: (v if k in ("router", "router_bias") else q(v))
+            for k, v in p.items()}
 
 
 def _top_k(logits, k):
     vals, ids = jax.lax.top_k(logits, k)
     return vals, ids
+
+
+def held_range(m):
+    """``(offset, count)`` of the experts a layer of ``MoEConfig`` ``m``
+    holds."""
+    return m.expert_offset, m.experts_held or m.num_experts
+
+
+#: rows of one tile of a held expert's assignments in a dropless layer
+DROPLESS_TILE = 128
+
+
+def _plain(m) -> bool:
+    """A softmax router, every expert held, under a capacity."""
+    return (m.scoring == "softmax" and m.routed_scaling_factor == 1.0
+            and m.capacity_factor is not None
+            and held_range(m)[1] == m.num_experts)
+
+
+def route(logits, m, bias=None):
+    """Top-``m.top_k`` routing over the last axis of ``logits`` (every
+    expert, held or not) → ``(weights, ids, scores)``.
+
+    ``softmax``: the probabilities, the k largest chosen.  ``sigmoid``
+    (DeepSeek-V3's ``noaux_tc``): ``s = sigmoid(logits)``; the choice is
+    made on ``c = s + bias`` alone: each of ``n_group`` groups scores the
+    sum of its two largest ``c``, the ``topk_group`` best groups are
+    kept, and the k largest ``c`` inside them are chosen; the weights
+    are the chosen ``s``.  Then the weights are divided by their sum
+    over all k, and they are multiplied by ``routed_scaling_factor``."""
+    k = m.top_k
+    if m.scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        vals, ids = _top_k(scores, k)
+    elif m.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores if bias is None else scores + bias
+        if m.n_group > 1:
+            e = logits.shape[-1]
+            per = e // m.n_group
+            best = jax.lax.top_k(
+                choice.reshape(choice.shape[:-1] + (m.n_group, per)),
+                min(2, per))[0].sum(axis=-1)
+            _, groups = jax.lax.top_k(best, m.topk_group)
+            kept = jnp.sum(jax.nn.one_hot(groups, m.n_group,
+                                          dtype=jnp.int32), axis=-2) > 0
+            choice = jnp.where(jnp.repeat(kept, per, axis=-1), choice,
+                               -jnp.inf)
+        _, ids = _top_k(choice, k)
+        vals = jnp.take_along_axis(scores, ids, axis=-1)
+    else:
+        raise ValueError(f"scoring={m.scoring!r}: 'softmax' or 'sigmoid'")
+    vals = vals / jnp.clip(jnp.sum(vals, axis=-1, keepdims=True), 1e-9)
+    if m.routed_scaling_factor != 1.0:
+        vals = vals * m.routed_scaling_factor
+    return vals, ids, scores
 
 
 def _hint(x, spec_axes, enable):
@@ -87,13 +161,106 @@ def _hint(x, spec_axes, enable):
 
 
 def moe_layer(p, x, cfg):
-    if cfg.moe_groups > 1:
+    """x: (B,S,D) -> (out (B,S,D), aux_loss scalar)."""
+    out, aux, _ = moe_layer_counted(p, x, cfg)
+    return out, aux
+
+
+def moe_layer_counted(p, x, cfg):
+    """``moe_layer`` and, per routing group, two int32 counts
+    ``(G, 2)``: the assignments routed to held experts, and those kept
+    under capacity.  A layer with no capacity takes the dropless path; a
+    plain layer routed as one group the flat one; every other layer the
+    grouped one (one group when ``moe_groups`` is 1)."""
+    if cfg.moe.capacity_factor is None:
+        return _moe_layer_dropless(p, x, cfg)
+    if cfg.moe_groups > 1 or not _plain(cfg.moe):
         return moe_layer_grouped(p, x, cfg)
     return _moe_layer_flat(p, x, cfg)
 
 
+def _aux_loss(probs, ids, m):
+    """Switch-style load-balancing loss over every leading axis."""
+    e = m.num_experts
+    lead = tuple(range(probs.ndim - 1))
+    me = jnp.mean(probs, axis=lead)
+    ce = jnp.mean(jnp.sum(jax.nn.one_hot(ids, e, dtype=jnp.float32),
+                          axis=-2), axis=lead)
+    return e * jnp.sum(me * ce) * m.router_aux_weight
+
+
+def _moe_layer_dropless(p, x, cfg):
+    """A layer with no capacity: every assignment to a held expert is
+    computed.  The held assignments are sorted by expert, each expert's
+    run padded to whole tiles of ``DROPLESS_TILE`` rows; a loop per held
+    expert runs as many tiles as its run fills, gathering the tiles'
+    tokens, and scatter-adds the weighted outputs into the tokens.  The
+    work follows the routed load; padding is under a tile an expert.
+    Routing is per token, so a token's answer does not depend on the
+    others; the counts are per group of ``moe_groups``, and all kept."""
+    m = cfg.moe
+    b, s, d = x.shape
+    n = b * s
+    k, g = m.top_k, cfg.moe_groups
+    offset, held = held_range(m)
+    t = DROPLESS_TILE
+    if cfg.moe_combine_shardmap:
+        raise NotImplementedError("shard_map dispatch keeps a capacity")
+    xf = x.reshape(n, d)
+
+    with jax.named_scope("router"):
+        logits = xf.astype(jnp.float32) @ p["router"]             # (N,E)
+        vals, ids, probs = route(logits, m, p.get("router_bias"))
+        aux = _aux_loss(probs, ids, m)
+
+    with jax.named_scope("dispatch"):
+        local = ids.reshape(-1) - offset                          # (N*k,)
+        mine = (local >= 0) & (local < held)
+        local = jnp.where(mine, local, held)       # held: not held here
+        order = jnp.argsort(local)                                # stable
+        sizes = jnp.bincount(local, length=held + 1)  # last: not held
+        starts = jnp.cumsum(sizes) - sizes
+        rank = jnp.zeros(n * k, jnp.int32).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32) - starts[local[order]])
+        tiles = (sizes + t - 1) // t
+        first = (jnp.cumsum(tiles) - tiles) * t    # each run's first row
+        # every held assignment fits, and each run adds under a tile
+        rows = -(-(n * min(k, held) + held * (t - 1)) // t) * t
+        at = jnp.where(mine, first[local] + rank, rows)
+        token_of = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)
+        tok = jnp.full((rows + 1,), n, jnp.int32).at[at].set(token_of)
+        w = jnp.zeros((rows + 1,), jnp.float32).at[at].set(
+            vals.reshape(-1).astype(jnp.float32))
+        xz = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)])
+        mine_g = jnp.sum(mine.reshape(g, -1), axis=1, dtype=jnp.int32)
+        counts = jnp.stack([mine_g, mine_g], axis=1)
+
+    with jax.named_scope("held_ffn"):
+        acc = jnp.zeros((n + 1, d), jnp.float32)
+        for j in range(held):
+            def tile(i, acc, j=j):
+                lo = first[j] + i * t
+                tt = jax.lax.dynamic_slice(tok, (lo,), (t,))
+                wt = jax.lax.dynamic_slice(w, (lo,), (t,))
+                xt = xz[tt]
+                h = xt @ p["w_up"][j]
+                if "w_gate" in p:
+                    h = _act(xt @ p["w_gate"][j], cfg.act) * h
+                else:
+                    h = _act(h, cfg.act)
+                y = (h @ p["w_down"][j]).astype(jnp.float32)
+                with jax.named_scope("combine"):
+                    return acc.at[tt].add(y * wt[:, None])
+            acc = jax.lax.fori_loop(0, tiles[j], tile, acc)
+
+    out = acc[:n].astype(x.dtype)
+    if "shared_up" in p:
+        out = out + _shared_ffn(p, xf, cfg)
+    return out.reshape(b, s, d), aux, counts
+
+
 def _moe_layer_flat(p, x, cfg):
-    """x: (B,S,D) -> (out (B,S,D), aux_loss scalar)."""
+    """x: (B,S,D) -> (out (B,S,D), aux_loss scalar, counts (1, 2))."""
     m = cfg.moe
     b, s, d = x.shape
     n = b * s
@@ -128,6 +295,8 @@ def _moe_layer_flat(p, x, cfg):
 
         keep = ranks < capacity
         slot = jnp.where(keep, flat_ids * capacity + ranks, e * capacity)
+        counts = jnp.stack([jnp.int32(n * k),
+                            jnp.sum(keep, dtype=jnp.int32)])[None]
 
         # ---- scatter tokens into the expert buffer -------------------
         token_of = jnp.repeat(jnp.arange(n), k)                   # (N*k,)
@@ -136,7 +305,7 @@ def _moe_layer_flat(p, x, cfg):
         expert_in = _hint(buf[:-1].reshape(e, capacity, d),
                           ("model", "data", None), hints)
 
-    with jax.named_scope("expert_ffn"):
+    with jax.named_scope("held_ffn"):
         # batched over experts
         h = jnp.einsum("ecd,edf->ecf", expert_in, p["w_up"])
         if "w_gate" in p:
@@ -163,18 +332,25 @@ def _moe_layer_flat(p, x, cfg):
 
     # ---- shared experts (always-on path) ------------------------------
     if "shared_up" in p:
+        out = out + _shared_ffn(p, xf, cfg)
+
+    return out.reshape(b, s, d), aux, counts
+
+
+def _shared_ffn(p, xf, cfg):
+    """The shared experts over every token ``xf`` (N, D)."""
+    with jax.named_scope("shared_ffn"):
         hs = xf @ p["shared_up"]
         if "shared_gate" in p:
             hs = _act(xf @ p["shared_gate"], cfg.act) * hs
         else:
             hs = _act(hs, cfg.act)
-        out = out + hs @ p["shared_down"]
-
-    return out.reshape(b, s, d), aux
+        return hs @ p["shared_down"]
 
 
 def moe_layer_grouped(p, x, cfg):
-    """§Perf (B2): group-local routing.
+    """§Perf (B2): group-local routing; returns ``moe_layer_counted``'s
+    triple.
 
     Tokens are split into ``moe_groups`` groups aligned with the
     data-parallel axis; ranking / capacity / dispatch happen *inside* each
@@ -186,34 +362,35 @@ def moe_layer_grouped(p, x, cfg):
     (the all-to-all equivalent).  Capacity is per group:
     C_loc = cf·n_loc·k/E (same expected load, stricter tail — the usual
     EP trade-off).
+
+    A layer that holds a share of the experts ranks every assignment as
+    above and keeps those of its own experts.
     """
     m = cfg.moe
     b, s, d = x.shape
     n = b * s
     e, k = m.num_experts, m.top_k
+    offset, held = held_range(m)
     g = cfg.moe_groups
     assert n % g == 0, (n, g)
     nl = n // g
     hints = cfg.moe_shard_hints
+    if cfg.moe_combine_shardmap and held < e:
+        raise NotImplementedError("shard_map dispatch holds every expert")
     xg = _hint(x.reshape(g, nl, d), ("data", None, None), hints)
 
     with jax.named_scope("router"):
         router_logits = xg.astype(jnp.float32) @ p["router"]      # (G,NL,E)
-        probs = jax.nn.softmax(router_logits, axis=-1)
-        top_vals, top_ids = _top_k(probs, k)                      # (G,NL,k)
-        top_vals = top_vals / jnp.clip(
-            jnp.sum(top_vals, axis=-1, keepdims=True), 1e-9)
-
-        me = jnp.mean(probs, axis=(0, 1))
-        ce = jnp.mean(jnp.sum(jax.nn.one_hot(top_ids, e,
-                                             dtype=jnp.float32),
-                              axis=2), axis=(0, 1))
-        aux = e * jnp.sum(me * ce) * m.router_aux_weight
+        top_vals, top_ids, probs = route(router_logits, m,
+                                         p.get("router_bias"))   # (G,NL,k)
+        aux = _aux_loss(probs, top_ids, m)
 
     cap = int(max(k, round(m.capacity_factor * nl * k / e)))
+    rows = held * cap                   # buffer rows of one group
 
     def rank_group(ids):
-        """ids: (NL,k) — group-local capacity ranking -> (slot, keep)."""
+        """ids: (NL,k) — group-local capacity ranking over every expert
+        -> (slot in the held buffer, keep, counts)."""
         flat_ids = ids.reshape(-1)
         sort_idx = jnp.argsort(flat_ids)
         counts = jnp.bincount(flat_ids, length=e)
@@ -221,17 +398,25 @@ def moe_layer_grouped(p, x, cfg):
         ranks_sorted = jnp.arange(nl * k) - starts[flat_ids[sort_idx]]
         ranks = jnp.zeros_like(ranks_sorted).at[sort_idx].set(ranks_sorted)
         keep = ranks < cap
-        slot = jnp.where(keep, flat_ids * cap + ranks, e * cap)
-        return slot, keep
+        if held < e:
+            flat_ids = flat_ids - offset
+            mine = (flat_ids >= 0) & (flat_ids < held)
+            keep = keep & mine
+            routed = jnp.sum(mine, dtype=jnp.int32)
+        else:
+            routed = jnp.int32(nl * k)
+        slot = jnp.where(keep, flat_ids * cap + ranks, rows)
+        return slot, keep, jnp.stack([routed,
+                                      jnp.sum(keep, dtype=jnp.int32)])
 
     def build_buf(xl, slot_g, keep_g):
         token_of = jnp.repeat(jnp.arange(nl), k)
-        buf = jnp.zeros((e * cap + 1, d), xl.dtype)
+        buf = jnp.zeros((rows + 1, d), xl.dtype)
         buf = buf.at[slot_g].set(xl[token_of], mode="drop")
-        return buf[:-1].reshape(e, cap, d)
+        return buf[:-1].reshape(held, cap, d)
 
     with jax.named_scope("dispatch"):
-        slot, keep = jax.vmap(rank_group)(top_ids)
+        slot, keep, tallies = jax.vmap(rank_group)(top_ids)
         if cfg.moe_combine_shardmap:
             # per model rank, build ONLY the local experts' buffers — the
             # forward dispatch needs no collective at all (§Perf B6)
@@ -241,7 +426,7 @@ def moe_layer_grouped(p, x, cfg):
             expert_in = jax.vmap(build_buf)(xg, slot, keep)
         expert_in = _hint(expert_in, ("data", "model", None, None), hints)
 
-    with jax.named_scope("expert_ffn"):
+    with jax.named_scope("held_ffn"):
         h = jnp.einsum("gecd,edf->gecf", expert_in, p["w_up"])
         if "w_gate" in p:
             h = _act(jnp.einsum("gecd,edf->gecf", expert_in, p["w_gate"]),
@@ -256,8 +441,8 @@ def moe_layer_grouped(p, x, cfg):
         # scatter-add combine: weighted contributions accumulate straight
         # into the (NL, D) token buffer, so the cross-shard reduction is
         # k× smaller than reducing the gathered (NL·k, D) tensor (§Perf B3)
-        flat = outs.reshape(e * cap, d)
-        contrib = flat[jnp.minimum(slot_g, e * cap - 1)] * \
+        flat = outs.reshape(rows, d)
+        contrib = flat[jnp.minimum(slot_g, rows - 1)] * \
             vals.reshape(-1)[:, None].astype(flat.dtype)     # (NL*k, D)
         token_of = jnp.repeat(jnp.arange(nl), k)
         idx = jnp.where(keep_g, token_of, nl)
@@ -275,14 +460,8 @@ def moe_layer_grouped(p, x, cfg):
         out = out.reshape(b, s, d)
 
     if "shared_up" in p:
-        xf = x.reshape(n, d)
-        hs = xf @ p["shared_up"]
-        if "shared_gate" in p:
-            hs = _act(xf @ p["shared_gate"], cfg.act) * hs
-        else:
-            hs = _act(hs, cfg.act)
-        out = out + (hs @ p["shared_down"]).reshape(b, s, d)
-    return out, aux
+        out = out + _shared_ffn(p, x.reshape(n, d), cfg).reshape(b, s, d)
+    return out, aux, tallies
 
 
 def _combine_shardmap(expert_out, slot, keep, vals, *, nl, e, cap, d, k):
@@ -402,7 +581,8 @@ def _combine_gspmd(expert_out, slot, keep, vals, *, nl, e, cap, d, k):
 
 
 def moe_layer_dense_ref(p, x, cfg):
-    """Oracle: run every expert on every token, combine by router weights.
+    """Oracle: run every held expert on every token, combine by router
+    weights.
 
     No capacity drops — used by tests to validate the dispatch path with a
     generous capacity factor (so nothing is dropped there either).
@@ -411,10 +591,8 @@ def moe_layer_dense_ref(p, x, cfg):
     b, s, d = x.shape
     xf = x.reshape(-1, d)
     router_logits = xf.astype(jnp.float32) @ p["router"]
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    top_vals, top_ids = _top_k(probs, m.top_k)
-    top_vals = top_vals / jnp.clip(
-        jnp.sum(top_vals, axis=-1, keepdims=True), 1e-9)
+    top_vals, top_ids, _ = route(router_logits, m, p.get("router_bias"))
+    offset, held = held_range(m)
     h = jnp.einsum("nd,edf->enf", xf, p["w_up"])
     if "w_gate" in p:
         h = _act(jnp.einsum("nd,edf->enf", xf, p["w_gate"]), cfg.act) * h
@@ -423,14 +601,9 @@ def moe_layer_dense_ref(p, x, cfg):
     every = jnp.einsum("enf,efd->end", h, p["w_down"])            # (E,N,D)
     weight = jnp.zeros((xf.shape[0], m.num_experts), jnp.float32)
     weight = weight.at[jnp.arange(xf.shape[0])[:, None], top_ids].set(
-        top_vals)
+        top_vals)[:, offset:offset + held]
     out = jnp.einsum("end,ne->nd", every.astype(jnp.float32), weight)
     out = out.astype(x.dtype)
     if "shared_up" in p:
-        hs = xf @ p["shared_up"]
-        if "shared_gate" in p:
-            hs = _act(xf @ p["shared_gate"], cfg.act) * hs
-        else:
-            hs = _act(hs, cfg.act)
-        out = out + hs @ p["shared_down"]
+        out = out + _shared_ffn(p, xf, cfg)
     return out.reshape(b, s, d)

@@ -52,6 +52,7 @@ nobody is waiting for, and all telemetry counters are lock-protected so
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import warnings
@@ -245,6 +246,14 @@ class SpanTotals:
             return {k: (c, s) for k, (c, s) in self._totals.items()}
 
 
+def count_live(totals, counts, n):
+    """``totals`` (C,) plus a layer's per-request ``counts`` (bucket, C)
+    summed over the dispatch's ``n`` live rows: padding rows are not
+    counted.  Traced inside a counting layer (``counter_names``)."""
+    live = (jnp.arange(counts.shape[0]) < n)[:, None]
+    return totals + jnp.sum(jnp.where(live, counts, 0), axis=0)
+
+
 def bucket_ladder(max_batch: int) -> Tuple[int, ...]:
     """Power-of-two batch buckets up to ``max_batch`` (which is always
     the top rung, even when it is not itself a power of two)."""
@@ -307,16 +316,27 @@ class CompiledModel:
     ``input_noun``            what a request payload is called in errors
     ``_layer_key(i, bucket)`` the full-identity cache key (incl. mesh)
     ``_layer_fn(i)``          ``(params, x) -> y`` traced per bucket
+                              (with counters: ``(params, x, totals, n)
+                              -> (y, totals)``)
     ``_layer_params(i)``      the pytree passed as ``params``
     ``_layer_in_sds(i, b)``   the ShapeDtypeStruct the layer is lowered at
     ``_empty_output()``       the zero-batch result
     ``_place_batch(xb, b)``   optional device placement (mesh sharding)
     ``sample_inputs(k)``      canonical request generator
     ``validate_input(x)``     per-workload admission check
+    ``counter_names``         counts each layer returns beside its output
     """
 
     kind = "model"                 # registry name of the workload
     input_noun = "input"           # request payload, as named in errors
+    #: names of the int32 counts that the layer executables keep: each
+    #: takes the running totals ``(len(counter_names),)`` and the
+    #: dispatch's live row count ``n`` beside its input, and returns
+    #: its output and the totals with its own counts of the live rows
+    #: added (``count_live``).  The totals stay on the device, with no
+    #: host sync per dispatch, and ``stats()`` reads them by these
+    #: names.  Empty: a layer maps its input alone (the CNN).
+    counter_names: Tuple[str, ...] = ()
 
     # subclass contract: these must be set before delegating to
     # ``CompiledModel.__init__`` (warmup compiles through them)
@@ -348,6 +368,9 @@ class CompiledModel:
         self.padded_rows = 0           # of which padding
         self.spans = SpanTotals()      # executor.* host time
         self._stats_lock = threading.Lock()
+        # the counters' totals, advanced by one dispatch at a time
+        self._counts = jnp.zeros(len(self.counter_names), jnp.int32)
+        self._counts_lock = threading.Lock()
         if warmup:
             self.warmup()
 
@@ -398,12 +421,20 @@ class CompiledModel:
             w_sds = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                 self._layer_params(i))
-            x_sds = self._layer_in_sds(i, bucket)
             with self._stats_lock:
                 self.compiles += 1
-            return jax.jit(fn).lower(w_sds, x_sds).compile()
+            return jax.jit(fn).lower(w_sds, self._layer_in_sds(i, bucket),
+                                     *self._counter_sds()).compile()
 
         return self.cache.get_or_build(self._layer_key(i, bucket), build)
+
+    def _counter_sds(self) -> list:
+        """A counting layer's arguments after its input: the totals and
+        the live row count (``counter_names``); none for the others."""
+        if not self.counter_names:
+            return []
+        return [jax.ShapeDtypeStruct(self._counts.shape, jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32)]
 
     def warmup(self) -> "CompiledModel":
         """AOT-compile every (layer, bucket) executable now, so no call
@@ -437,19 +468,29 @@ class CompiledModel:
                 xb = jnp.concatenate([xb, pad])
         xb = self._place_batch(xb, bucket)
         act = xb
-        for i in range(self.num_layers):
-            if should_abort is not None and should_abort():
-                raise DispatchAborted(
-                    f"dispatch abandoned before layer {i} "
-                    f"(all served requests cancelled)")
-            # dispatch is asynchronous: this times the host's launch
-            with self.spans.span("executor.launch", layer=i):
-                act = self._compile_layer(i, bucket)(
-                    self._layer_params(i), act)
-        with self._stats_lock:
-            self.bucket_hits[bucket] += 1
-            self.rows += bucket
-            self.padded_rows += bucket - n
+        # a counting dispatch advances the totals layer by layer, so
+        # dispatches that overlap take their turns
+        with (self._counts_lock if self.counter_names
+              else contextlib.nullcontext()):
+            totals = self._counts
+            for i in range(self.num_layers):
+                if should_abort is not None and should_abort():
+                    raise DispatchAborted(
+                        f"dispatch abandoned before layer {i} "
+                        f"(all served requests cancelled)")
+                # dispatch is asynchronous: this times the host's launch
+                with self.spans.span("executor.launch", layer=i):
+                    exe = self._compile_layer(i, bucket)
+                    if self.counter_names:
+                        act, totals = exe(self._layer_params(i), act,
+                                          totals, np.int32(n))
+                    else:
+                        act = exe(self._layer_params(i), act)
+            with self._stats_lock:
+                self._counts = totals
+                self.bucket_hits[bucket] += 1
+                self.rows += bucket
+                self.padded_rows += bucket - n
         return act[:n]
 
     def __call__(self, x, *, should_abort=None):
@@ -491,15 +532,23 @@ class CompiledModel:
         a second plan over identical layers reports 0.  ``rows`` and
         ``padded_rows`` count the rows the buckets ran and how many of
         them were padding; ``spans`` is the executor's host time per
-        span (``SpanTotals.snapshot``).  Snapshot is lock-consistent
-        under the async drain."""
+        span (``SpanTotals.snapshot``); each of ``counter_names`` gives
+        its sum over every dispatch so far (reading it waits for the
+        last dispatch: ``executor.counts``).  Snapshot is
+        lock-consistent under the async drain."""
         with self._stats_lock:
             hits = dict(self.bucket_hits)
             calls = self.calls
             compiles = self.compiles
             rows, padded_rows = self.rows, self.padded_rows
+            totals = self._counts
+        counted = {}
+        if self.counter_names:
+            with self.spans.span("executor.counts"):
+                counted = dict(zip(self.counter_names,
+                                   np.asarray(totals).tolist()))
         cache = self.cache.stats()
-        return {
+        return {**counted,
             "kind": self.kind,
             "buckets": list(self.buckets),
             "bucket_hits": hits,

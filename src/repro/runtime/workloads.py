@@ -62,7 +62,8 @@ from repro.core.deploy import (DEFAULT_BIT_CANDIDATES, DeploymentError,
                                device_profile)
 from repro.models import moe as moe_mod
 from repro.models.layers import split_keys
-from repro.runtime.compiled import CompiledModel, ExecutableCache
+from repro.runtime.compiled import (CompiledModel, ExecutableCache,
+                                    count_live)
 
 #: registry block name for an MoE layer's assignment (LayerAssignment
 #: .block is a string either way; conv blocks come from repro.blocks,
@@ -229,18 +230,46 @@ class CNNWorkloadSpec(WorkloadSpec):
 # MoE: quantized mixture-of-experts inference
 # ---------------------------------------------------------------------------
 
+#: the routing fields of an ``MoELayerSpec`` (``models.moe.route``) and
+#: the held share of its experts, with their JSON types and the tags
+#: that name them in an executable's name
+_ROUTING = {"scoring": (str, ""), "n_group": (int, "g"),
+            "topk_group": (int, "t"), "routed_scaling_factor": (float, "x"),
+            "experts_held": (int, "h"), "expert_offset": (int, "o")}
+
+
+def _opt_float(v):
+    return None if v is None else float(v)
+
+
 @dataclass(frozen=True)
 class MoELayerSpec:
     """One MoE layer's geometry + planned quantization.  The typed
     per-layer spec the v2 plan schema carries for MoE workloads (the
-    analogue of ``ConvLayerSpec``)."""
+    analogue of ``ConvLayerSpec``).
+
+    ``num_experts`` is the router's width.  The layer holds
+    ``experts_held`` of them (None: all) from id ``expert_offset``, as
+    one chip of an expert-parallel deployment does, and routes with
+    ``scoring`` (``"softmax"``, or DeepSeek-V3's ``"sigmoid"`` with a
+    correction bias and ``n_group``/``topk_group`` group-limited
+    top-k) and ``routed_scaling_factor``, the weights renormalized over
+    the k.  The defaults are a softmax router over experts that are all
+    held.  ``capacity_factor`` None drops nothing (``models.moe``'s
+    dropless path), as DeepSeek-V3 serves."""
     d_ff_expert: int
     num_experts: int
     top_k: int
     data_bits: int = 8             # activation fake-quant grid
     coeff_bits: int = 8            # expert-weight fake-quant grid
     n_shared_experts: int = 0
-    capacity_factor: float = 2.0
+    capacity_factor: Optional[float] = 2.0
+    scoring: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
 
     def __post_init__(self):
         if self.top_k < 1 or self.top_k > self.num_experts:
@@ -251,6 +280,43 @@ class MoELayerSpec:
             v = getattr(self, name)
             if not 2 <= v <= 16:
                 raise ValueError(f"{name}={v} outside [2, 16]")
+        if self.capacity_factor is not None and self.capacity_factor <= 0:
+            raise ValueError(f"capacity_factor={self.capacity_factor}: "
+                             f"positive, or None for no capacity")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring={self.scoring!r}: 'softmax' or "
+                             f"'sigmoid'")
+        if (self.n_group < 1 or self.num_experts % self.n_group
+                or not 1 <= self.topk_group <= self.n_group
+                or self.top_k > self.topk_group * self.num_experts
+                // self.n_group):
+            raise ValueError(
+                f"n_group={self.n_group}, topk_group={self.topk_group}: "
+                f"the groups must split {self.num_experts} experts evenly "
+                f"and the kept groups hold top_k={self.top_k}")
+        if self.n_group > 1 and self.scoring != "sigmoid":
+            raise ValueError("group-limited routing is sigmoid scoring's")
+        if ((self.experts_held is not None and self.experts_held < 1)
+                or not 0 <= self.expert_offset
+                <= self.num_experts - self.held):
+            raise ValueError(
+                f"experts_held={self.experts_held} from expert_offset="
+                f"{self.expert_offset} does not lie within "
+                f"{self.num_experts} experts")
+
+    @property
+    def held(self) -> int:
+        """How many experts the layer holds."""
+        return self.experts_held or self.num_experts
+
+    def routing(self) -> dict:
+        """The routing and held-share fields that differ from their
+        defaults, by name: what the plan's payload, the executable's
+        cache key and its name add for this layer (a default layer's
+        read as they did before these fields existed)."""
+        defaults = {f.name: f.default for f in dataclasses.fields(self)}
+        return {name: getattr(self, name) for name in _ROUTING
+                if getattr(self, name) != defaults[name]}
 
 
 @register_workload
@@ -288,7 +354,9 @@ class MoEWorkloadSpec(WorkloadSpec):
                 "data_bits": int(s.data_bits),
                 "coeff_bits": int(s.coeff_bits),
                 "n_shared_experts": int(s.n_shared_experts),
-                "capacity_factor": float(s.capacity_factor),
+                "capacity_factor": _opt_float(s.capacity_factor),
+                **{name: _ROUTING[name][0](v)
+                   for name, v in s.routing().items()},
             } for s in self.layers],
         }
 
@@ -302,7 +370,9 @@ class MoEWorkloadSpec(WorkloadSpec):
                 data_bits=int(s["data_bits"]),
                 coeff_bits=int(s["coeff_bits"]),
                 n_shared_experts=int(s["n_shared_experts"]),
-                capacity_factor=float(s["capacity_factor"]))
+                capacity_factor=_opt_float(s["capacity_factor"]),
+                **{name: cast(s[name]) for name, (cast, _) in
+                   _ROUTING.items() if name in s})
                 for s in payload["layers"]),
             d_model=int(payload["d_model"]),
             seq_len=int(payload["seq_len"]),
@@ -324,11 +394,13 @@ class MoEWorkloadSpec(WorkloadSpec):
             moe=MoEConfig(num_experts=s.num_experts, top_k=s.top_k,
                           d_ff_expert=s.d_ff_expert,
                           n_shared_experts=s.n_shared_experts,
-                          capacity_factor=s.capacity_factor),
+                          capacity_factor=s.capacity_factor,
+                          **{name: getattr(s, name) for name in _ROUTING}),
             d_model=self.d_model, act=self.act, mlp_gated=self.mlp_gated)
 
     def init_params(self, key, *, quantized: bool = True) -> list:
-        """Per-layer ``init_moe`` draws (float32), expert weights
+        """Per-layer ``init_moe`` draws (float32: the held experts, the
+        router over all of them and a sigmoid router's bias), expert weights
         fake-quantized to each layer's ``coeff_bits`` grid unless
         ``quantized=False`` (the float oracle draw)."""
         return list(self.iter_params(key, quantized=quantized))
@@ -364,14 +436,16 @@ class _MoELayerModelCfg:
 
 def _route_per_block(p, x, cfg):
     """One MoE layer over a batch of token blocks with each block routed
-    on its own (``moe_groups`` = blocks, so expert capacity is per
-    block): a block's output never depends on which blocks share its
+    on its own (``moe_groups`` = blocks, so expert capacity, where the
+    layer has one, is per block): a block's output never depends on which blocks share its
     dispatch or on bucket padding — the routing twin of ``_fake_quant``'s
-    per-token scale.  The aux (load-balancing) loss is a training
-    quantity; inference drops it."""
-    y, _aux = moe_mod.moe_layer(
+    per-token scale.  Returns the output and, per block, the
+    assignments routed to held experts and those kept (``(B, 2)``
+    int32).  The aux (load-balancing) loss is a training quantity;
+    inference drops it."""
+    y, _aux, counts = moe_mod.moe_layer_counted(
         p, x, dataclasses.replace(cfg, moe_groups=x.shape[0]))
-    return y
+    return y, counts
 
 
 def _fake_quant(x, bits: int):
@@ -397,6 +471,7 @@ class CompiledMoE(CompiledModel):
 
     kind = "moe"
     input_noun = "token block"
+    counter_names = ("moe_routed_held", "moe_kept_held")
 
     def __init__(self, spec: MoEWorkloadSpec, params, *,
                  max_batch: int = 16, mesh=None, warmup: bool = True,
@@ -433,24 +508,29 @@ class CompiledMoE(CompiledModel):
         s = self.spec.layers[i]
         return (MOE_BLOCK_NAME, self.spec.d_model, s.d_ff_expert,
                 s.num_experts, s.top_k, s.n_shared_experts,
-                float(s.capacity_factor), s.data_bits, s.coeff_bits,
-                self.spec.seq_len, self.spec.act, self.spec.mlp_gated,
-                self._mesh_token, bucket)
+                _opt_float(s.capacity_factor), s.data_bits, s.coeff_bits,
+                self.spec.seq_len, self.spec.act, self.spec.mlp_gated
+                ) + tuple(s.routing().items()) + (self._mesh_token, bucket)
 
     def _layer_fn(self, i: int):
         cfg = self.spec.layer_cfg(i)
         data_bits = self.spec.layers[i].data_bits
 
-        def layer(p, x):
-            # residual MoE block over the quantized activation grid
-            return x + _route_per_block(p, _fake_quant(x, data_bits), cfg)
+        def layer(p, x, totals, n):
+            # residual MoE block over the quantized activation grid, and
+            # the totals with the live blocks' counts (``counter_names``)
+            y, counts = _route_per_block(p, _fake_quant(x, data_bits), cfg)
+            return x + y, count_live(totals, counts, n)
 
         # the executable's name in a trace, e.g. jit_moe_e128_k8_d4c4:
         # from the layer's content, as the cache key is, since identical
-        # layers share one executable
+        # layers share one executable; routing fields that differ from
+        # their defaults follow (..._sigmoid_g8_t4_x2p5_h8)
         s = self.spec.layers[i]
+        tags = "".join(f"_{_ROUTING[name][1]}{str(v).replace('.', 'p')}"
+                       for name, v in s.routing().items())
         layer.__name__ = (f"moe_e{s.num_experts}_k{s.top_k}"
-                          f"_d{s.data_bits}c{s.coeff_bits}")
+                          f"_d{s.data_bits}c{s.coeff_bits}{tags}")
         return layer
 
     def _layer_params(self, i: int):
@@ -503,23 +583,27 @@ def moe_layer_demand(spec: MoEWorkloadSpec, layer: MoELayerSpec,
     budget units: matmul MACs (``mxu_cost``), weight traffic at the
     quantized container width plus activation traffic (``hbm_bytes``),
     elementwise work (``vpu_ops``), and the expert-buffer + one-weight
-    working set (``vmem_bytes``, a capacity).  The MoE twin of
+    working set (``vmem_bytes``, a capacity), of the experts the layer
+    holds (the router spans all of them).  The MoE twin of
     ``deploy.predict_layer_demand`` — analytic rather than sweep-fitted
     because the expert FFN is dense matmul, the regime the roofline
     model is exact in."""
     S, d = spec.seq_len, spec.d_model
     fe, e, k = layer.d_ff_expert, layer.num_experts, layer.top_k
+    held = layer.held
     fs = fe * layer.n_shared_experts
     nmats = 3 if spec.mlp_gated else 2
-    routed = S * k                      # expert-token assignments
-    mxu = (S * d * e                    # router projection
+    routed = S * k * held / e           # assignments to held experts
+    mxu = (S * d * e                    # router projection (every expert)
            + nmats * routed * d * fe    # expert FFN on dispatched tokens
            + nmats * S * d * fs)        # always-on shared experts
-    weight_bytes = (nmats * e * d * fe + nmats * d * fs) * coeff_bits / 8
+    weight_bytes = (nmats * held * d * fe + nmats * d * fs) * coeff_bits / 8
     act_bytes = S * d * data_bits / 8
-    vpu = S * (e + k * fe + d)          # softmax + act + combine
-    cap = int(max(k, round(layer.capacity_factor * S * k / e)))
-    vmem = float(e * cap * d * 4 + e * d * fe * 4)
+    vpu = S * (e + k * fe + d)          # router + act + combine
+    # the expert buffer: held·C rows, or a dropless layer's one tile
+    buf = (moe_mod.DROPLESS_TILE if layer.capacity_factor is None else
+           held * int(max(k, round(layer.capacity_factor * S * k / e))))
+    vmem = float(buf * d * 4 + held * d * fe * 4)
     return {"mxu_cost": float(mxu),
             "hbm_bytes": float(weight_bytes + act_bytes),
             "vpu_ops": float(vpu), "vmem_bytes": vmem}
@@ -636,7 +720,7 @@ def _eager_forward(spec: MoEWorkloadSpec, params, x, *,
     for i in range(len(spec.layers)):
         xi = (_fake_quant(act, spec.layers[i].data_bits)
               if quant_act else act)
-        act = act + _route_per_block(params[i], xi, spec.layer_cfg(i))
+        act = act + _route_per_block(params[i], xi, spec.layer_cfg(i))[0]
     return act
 
 
@@ -666,7 +750,8 @@ def moe_quantization_error(spec: MoEWorkloadSpec, *, key=None,
     for i, pf in enumerate(spec.iter_params(key, quantized=False)):
         s, cfg = spec.layers[i], spec.layer_cfg(i)
         pq = _quantize_moe(pf, s.coeff_bits)
-        yq = yq + _route_per_block(pq, _fake_quant(yq, s.data_bits), cfg)
+        yq = yq + _route_per_block(pq, _fake_quant(yq, s.data_bits),
+                                   cfg)[0]
         yf = yf + moe_mod.moe_layer_dense_ref(pf, yf, cfg)
         del pf, pq
     num = float(jnp.sqrt(jnp.mean((yq - yf) ** 2)))

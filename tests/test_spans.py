@@ -166,7 +166,8 @@ def test_layer_executables_have_stable_names_and_moe_scopes():
     """Each layer executable is named for what it computes (the trace's
     module name): block, bits and channels, or experts, top-k and bits;
     and the MoE layers carry the
-    router, dispatch, expert FFN and combine scopes in their metadata."""
+    router, dispatch, held experts' FFN and combine scopes in their
+    metadata."""
     from repro.runtime.workloads import compile_plan, plan_moe_deployment
     from test_workloads import tiny_moe_spec
 
@@ -185,5 +186,5 @@ def test_layer_executables_have_stable_names_and_moe_scopes():
     for bucket in moe.buckets:         # flat (1) and grouped (2) routing
         text = moe._compile_layer(0, bucket).as_text()
         assert text.startswith(f"HloModule {name},")
-        for scope in ("router", "dispatch", "expert_ffn", "combine"):
+        for scope in ("router", "dispatch", "held_ffn", "combine"):
             assert f"/{scope}/" in text, (bucket, scope)

@@ -157,7 +157,7 @@ def test_moe_layer_full_width_compiles(tpu_compile):
         lambda: spec.init_params(jax.random.PRNGKey(0)))
     model = CompiledMoE(spec, params, max_batch=4, warmup=False)
     exe = tpu_compile(model._layer_fn(0), params[0],
-                      model._layer_in_sds(0, 4))
+                      model._layer_in_sds(0, 4), *model._counter_sds())
     mem = exe.memory_analysis()
     assert mem.argument_size_in_bytes > 2e9          # the expert weights
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes

@@ -439,3 +439,158 @@ def test_moe_workload_from_dense_config_raises():
     cfg = smoke_config("llama3.2-3b")
     with pytest.raises(ValueError, match="no MoE block"):
         moe_workload_from_config(cfg)
+
+
+# ---------------------------------------------------------------------------
+# a layer holding a share of its experts, with DeepSeek-V3's router and a
+# shared expert: plan fields, served path against the plain reference,
+# counters, demand
+# ---------------------------------------------------------------------------
+
+def ep_spec(n_layers=2, **kw):
+    """d 64, 32 experts in 4 groups, top-4 from the best 2, 8 held,
+    one shared expert, no capacity, blocks of 16 tokens."""
+    from test_moe import EP
+    layer = MoELayerSpec(**{**EP, "experts_held": 8, "data_bits": 8,
+                            "coeff_bits": 6, **kw})
+    return MoEWorkloadSpec(layers=(layer,) * n_layers, d_model=64,
+                           seq_len=16)
+
+
+def _plan_text(path):
+    text = path.read_text()
+    return text if text.endswith("\n") else text + "\n"
+
+
+def test_routing_fields_round_trip_through_the_plan():
+    plan = plan_moe_deployment(ep_spec(expert_offset=8), "v5e",
+                               bit_candidates=None, on_infeasible="fallback")
+    back = DeploymentPlan.from_json(plan.to_json())
+    assert back == plan and back.workload == plan.workload
+    layer = json.loads(plan.to_json())["workload"]["spec"]["layers"][0]
+    assert {k: layer[k] for k in ("scoring", "n_group", "topk_group",
+                                  "routed_scaling_factor", "experts_held",
+                                  "expert_offset")} == {
+        "scoring": "sigmoid", "n_group": 4, "topk_group": 2,
+        "routed_scaling_factor": 2.5, "experts_held": 8, "expert_offset": 8}
+    assert layer["capacity_factor"] is None
+    # a default layer's payload carries the keys it always had
+    plain = MoEWorkloadSpec(layers=(MoELayerSpec(16, 4, 2),), d_model=8)
+    assert set(plain.to_payload()["layers"][0]) == {
+        "d_ff_expert", "num_experts", "top_k", "data_bits", "coeff_bits",
+        "n_shared_experts", "capacity_factor"}
+
+
+@pytest.mark.parametrize("path", [
+    "tests/golden/plan_moe_golden.json",
+    "chipbench/configs/qwen3-moe-30b-a3b-2L.plan.json"])
+def test_committed_moe_plans_read_unchanged(path):
+    from pathlib import Path
+    p = Path(__file__).resolve().parents[1] / path
+    plan = DeploymentPlan.from_json(p.read_text())
+    assert plan.to_json() + "\n" == _plan_text(p)
+    assert all(s.routing() == {} and s.held == s.num_experts
+               for s in plan.workload.layers)
+
+
+def _fq(x, bits):
+    hi = float((1 << (bits - 1)) - 1)
+    s = hi / np.maximum(np.max(np.abs(x), axis=-1, keepdims=True), 1e-6)
+    return np.round(x * s) / s
+
+
+@pytest.mark.parametrize("cf", [None, 2.0], ids=["dropless", "capped"])
+def test_compiled_held_layers_serve_the_plain_reference(cf):
+    """Two held-share layers through ``compile_plan`` (a padded bucket of
+    4 for 3 requests, then a bucket of 1) against the plain reference
+    block by block; the counters sum the live blocks' counts only, and
+    the executables carry the routing fields in their name and key."""
+    import jax
+    from test_moe import reference_layer
+    spec = ep_spec(capacity_factor=cf)
+    plan = plan_moe_deployment(spec, "v5e", bit_candidates=None,
+                               on_infeasible="fallback")
+    with jax.default_matmul_precision("highest"):
+        moe = compile_plan(plan, key=jax.random.PRNGKey(4), max_batch=4)
+        rng = np.random.default_rng(1)
+        xs = rng.standard_normal((4, 16, 64)).astype(np.float32)
+        got = np.concatenate([np.asarray(moe(xs[:3])),
+                              np.asarray(moe(xs[3:]))])
+        act, routed, kept = xs.copy(), 0, 0
+        for i, s in enumerate(moe.spec.layers):
+            cfg = moe.spec.layer_cfg(i)
+            for b in range(len(act)):
+                add, (r, k) = reference_layer(moe.params[i],
+                                              _fq(act[b], s.data_bits),
+                                              cfg.moe)
+                act[b] = act[b] + np.asarray(add)
+                routed, kept = routed + r, kept + k
+    np.testing.assert_allclose(got, act, rtol=1e-5, atol=1e-5)
+    st = moe.stats()
+    assert (st["moe_routed_held"], st["moe_kept_held"]) == (routed, kept)
+    assert 0 < kept < routed if cf else 0 < kept == routed
+    assert st["padded_rows"] == 1
+    assert moe.params[0]["w_up"].shape == (8, 64, 32)
+    assert moe.params[0]["router"].shape == (64, 32)
+    text = moe._compile_layer(0, 4).as_text()
+    assert text.split(",")[0] == (
+        "HloModule jit_moe_e32_k4_d8c6_sigmoid_g4_t2_x2p5_h8")
+    assert all(f"/{scope}/" in text for scope in (
+        "router", "dispatch", "held_ffn", "shared_ffn", "combine"))
+    assert moe._layer_key(0, 4)[12:-2] == (
+        ("scoring", "sigmoid"), ("n_group", 4), ("topk_group", 2),
+        ("routed_scaling_factor", 2.5), ("experts_held", 8))
+
+
+def test_a_default_layer_keeps_its_key_and_a_cnn_counts_nothing():
+    moe = CompiledMoE(tiny_moe_spec(1), tiny_moe_spec(1).init_params(
+        __import__("jax").random.PRNGKey(0)), max_batch=2)
+    s = moe.spec.layers[0]
+    assert moe._layer_key(0, 2) == (
+        "moe_ffn", 8, 16, 4, 2, 0, 2.0, s.data_bits, s.coeff_bits, 8,
+        "silu", True, None, 2)
+    moe(np.zeros((2, 8, 8), np.float32))
+    # a zero block routes its 8 tokens × top-2 by the router's ties alone
+    assert moe.stats()["moe_routed_held"] == 2 * 8 * 2
+    cnn = compile_plan(_cnn_plan(), max_batch=2)
+    cnn(np.stack(cnn.sample_inputs(2)))
+    assert "moe_kept_held" not in cnn.stats()
+
+
+def test_moe_layer_demand_counts_held_experts_only():
+    """Weights and the VMEM working set of a layer holding 8 of 32
+    experts are those of the 8 (and the shared expert's weights); the
+    routed MACs are the held experts' share."""
+    from repro.runtime.workloads import moe_layer_demand
+    spec = ep_spec(1)
+    held = spec.layers[0]
+    whole = dataclasses.replace(held, experts_held=None)
+    d, fe, S, k, e = 64, 32, 16, 4, 32
+    a = moe_layer_demand(spec, held, 8, 4)
+    b = moe_layer_demand(spec, whole, 8, 4)
+    assert a["hbm_bytes"] == (3 * 8 * d * fe + 3 * d * fe) * 4 / 8 \
+        + S * d * 8 / 8
+    assert b["hbm_bytes"] == (3 * 32 * d * fe + 3 * d * fe) * 4 / 8 \
+        + S * d * 8 / 8
+    # capped at 2.0: 4 rows an expert; dropless: one tile
+    cap = 4
+    capped = moe_layer_demand(spec, dataclasses.replace(
+        held, capacity_factor=2.0), 8, 4)
+    assert capped["vmem_bytes"] == 8 * cap * d * 4 + 8 * d * fe * 4
+    assert a["vmem_bytes"] == 128 * d * 4 + 8 * d * fe * 4
+    assert b["vmem_bytes"] == 128 * d * 4 + 32 * d * fe * 4
+    assert a["mxu_cost"] == S * d * e + 3 * S * k * 8 / e * d * fe \
+        + 3 * S * d * fe
+
+
+@pytest.mark.parametrize("kw", [
+    {"experts_held": 0}, {"experts_held": 33},
+    {"experts_held": 8, "expert_offset": 25}, {"scoring": "relu"},
+    {"n_group": 5}, {"topk_group": 5}, {"topk_group": 0},
+    {"scoring": "softmax"},          # group-limited top-k without sigmoid
+    {"top_k": 20},                   # more than the 2 kept groups hold
+    {"capacity_factor": 0.0},
+])
+def test_invalid_routing_fields_are_refused(kw):
+    with pytest.raises(ValueError):
+        ep_spec(1, **kw)
