@@ -331,6 +331,110 @@ def test_gateway_serves_bit_exact():
     assert sum(k * v for k, v in stats["occupancy_hist"].items()) == 9
 
 
+def test_gateway_stacks_each_batch_into_a_kept_buffer():
+    """Every dispatch stacks its batch into the plan's one kept host
+    batch (``max_inflight`` 1), answers stay bit-exact after later
+    batches overwrite it, and a dispatch that fails drops its batch."""
+    plan = _plan()
+    gw = AsyncCNNGateway.from_plan(
+        plan, AsyncServeConfig(max_batch=4, max_pending=16))
+    entry = gw.plans["plan0"]
+    compiled = entry.compiled
+    seen, broken = [], [False]
+
+    class _Recording:
+        def __init__(self, inner):
+            self._inner = inner
+
+        def __getattr__(self, name):
+            return getattr(self._inner, name)
+
+        def __call__(self, xb, **k):
+            seen.append(xb.base)
+            if broken[0]:
+                raise RuntimeError("device exploded")
+            return self._inner(xb, **k)
+
+    entry.compiled = _Recording(compiled)
+    imgs = _images(compiled, 9, seed=8)
+
+    async def main():
+        async with gw:
+            outs = []
+            for chunk in (imgs[:4], imgs[4:8], imgs[8:]):
+                futs = [gw.submit_nowait(img) for img in chunk]
+                outs += await asyncio.gather(*futs)
+            broken[0] = True
+            failed = await asyncio.gather(gw.submit_nowait(imgs[0]),
+                                          return_exceptions=True)
+            return outs, failed
+
+    outs, failed = asyncio.run(main())
+    pcfg = deploy.plan_config(plan)
+    for img, out in zip(imgs, outs):
+        np.testing.assert_array_equal(out, np.asarray(
+            cnn_forward_ref(compiled.params, jnp.asarray(img), pcfg)))
+    assert isinstance(failed[0], RuntimeError)
+    kept = seen[0]
+    assert len(seen) >= 4 and all(b is kept for b in seen)
+    assert kept.shape == (4,) + compiled.in_shape
+    assert entry.staging == []          # the failed dispatch dropped it
+
+
+def test_gateway_dispatches_in_flight_never_share_a_staging_batch():
+    """With two dispatches in flight (``max_inflight`` 2) the second
+    stacks into a second kept batch, so the first, still reading its
+    rows, answers from its own input."""
+    import threading
+    gate = threading.Event()
+    bases = []
+
+    class _GatedFirst:
+        kind = "cnn"
+        in_shape = (4, 4, 1)
+        in_dtype = np.int8
+        max_batch = 2
+
+        def validate_input(self, x, request_id=0):
+            return np.asarray(x, self.in_dtype)
+
+        def __call__(self, xb, should_abort=None):
+            bases.append(xb.base)
+            if len(bases) == 1:
+                assert gate.wait(timeout=10)
+            return np.asarray(xb) * 2
+
+    gw = AsyncCNNGateway(AsyncServeConfig(max_batch=2, max_inflight=2,
+                                          max_pending=8))
+    gw.register_plan(None, plan_id="p", compiled=_GatedFirst())
+
+    async def until(done):
+        for _ in range(2000):
+            if done():
+                return
+            await asyncio.sleep(0.005)
+        raise AssertionError("the gateway never got there")
+
+    async def main():
+        async with gw:
+            first = [gw.submit_nowait(np.full((4, 4, 1), 1, np.int8),
+                                      plan_id="p") for _ in range(2)]
+            await until(lambda: len(bases) == 1)    # held in the worker
+            second = [gw.submit_nowait(np.full((4, 4, 1), 3, np.int8),
+                                       plan_id="p") for _ in range(2)]
+            await until(lambda: gw.spans.snapshot().get(
+                "gateway.stack", (0, 0))[0] == 2)   # stacked
+            gate.set()
+            return (await asyncio.gather(*first),
+                    await asyncio.gather(*second))
+
+    first, second = asyncio.run(main())
+    assert all((y == 2).all() for y in first)
+    assert all((y == 6).all() for y in second)
+    assert len(bases) == 2 and bases[0] is not bases[1]
+    assert len(gw.plans["p"].staging) == 2
+
+
 def test_gateway_backpressure_and_load_shedding():
     """submit_nowait sheds load at the bound; submit awaits space and
     completes once the drain frees it."""
