@@ -98,18 +98,13 @@ class ModelConfig:
     act: str = "silu"                # silu | gelu
     mlp_gated: bool = True           # gated (3-matrix) vs plain (2-matrix) MLP
     scale_embeddings: bool = False   # multiply embeddings by sqrt(d_model)
-    # perf knobs (§Perf): resharding hints applied inside the model
+    # perf knobs (§Perf)
     attn_batch_shard: bool = False   # shard attention over (data, model)
                                      # batch when heads don't divide TP
     attn_logits_bf16: bool = False   # keep attention logits in bf16
-    moe_shard_hints: bool = False    # constrain expert buffers to
-                                     # (E→model, capacity→data) sharding
-    moe_groups: int = 1              # >1: route per token-group (aligned
-                                     # to the data axis) — local dispatch,
-                                     # no global sort/scatter collectives
-    moe_combine_shardmap: bool = False  # explicit shard_map combine: one
-                                        # psum(NL·D) instead of the k×
-                                        # larger gather all-reduce
+    moe_groups: int = 1              # MoE capacity ranking per token-group
+                                     # (>1: aligned to the data axis, no
+                                     # global sort across groups)
     remat_policy: str = "full"       # full | save_mixer_out — the latter
                                      # keeps sublayer outputs so backward
                                      # never re-runs forward collectives

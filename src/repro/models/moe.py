@@ -14,15 +14,13 @@ router still scores every expert and ranks are computed over all of
 them, but the buffer has rows only for the held experts, and what the
 others would add is left out of the sum (their weights still count in
 the normalization).  ``route`` also has DeepSeek-V3's sigmoid,
-group-limited router with its correction bias and routed scaling.  A
-layer with no capacity (``capacity_factor`` None) drops nothing: its
-held assignments are sorted by expert and run tile by tile
-(``_moe_layer_dropless``), as many tiles as they fill.
+group-limited router with its correction bias and routed scaling.
 
-Expert parallelism: the expert axis of w_up/w_gate/w_down is sharded over
-the ``model`` mesh axis (see parallel/sharding.py); the scatter/gather pair
-is GSPMD's to schedule in the baseline, and is replaced by an explicit
-``shard_map`` + ``all_to_all`` in the optimized path (§Perf).
+A layer with a capacity runs ``moe_layer_grouped``, its tokens ranked
+in ``moe_groups`` groups, one or many.  A layer with no capacity
+(``capacity_factor`` None) drops nothing: its held assignments are
+sorted by expert and run tile by tile (``_moe_layer_dropless``), as
+many tiles as they fill.
 """
 
 from __future__ import annotations
@@ -81,11 +79,6 @@ def quantize_moe_params(p, coeff_bits: int):
             for k, v in p.items()}
 
 
-def _top_k(logits, k):
-    vals, ids = jax.lax.top_k(logits, k)
-    return vals, ids
-
-
 def held_range(m):
     """``(offset, count)`` of the experts a layer of ``MoEConfig`` ``m``
     holds."""
@@ -94,13 +87,6 @@ def held_range(m):
 
 #: rows of one tile of a held expert's assignments in a dropless layer
 DROPLESS_TILE = 128
-
-
-def _plain(m) -> bool:
-    """A softmax router, every expert held, under a capacity."""
-    return (m.scoring == "softmax" and m.routed_scaling_factor == 1.0
-            and m.capacity_factor is not None
-            and held_range(m)[1] == m.num_experts)
 
 
 def route(logits, m, bias=None):
@@ -117,7 +103,7 @@ def route(logits, m, bias=None):
     k = m.top_k
     if m.scoring == "softmax":
         scores = jax.nn.softmax(logits, axis=-1)
-        vals, ids = _top_k(scores, k)
+        vals, ids = jax.lax.top_k(scores, k)
     elif m.scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         choice = scores if bias is None else scores + bias
@@ -132,7 +118,7 @@ def route(logits, m, bias=None):
                                           dtype=jnp.int32), axis=-2) > 0
             choice = jnp.where(jnp.repeat(kept, per, axis=-1), choice,
                                -jnp.inf)
-        _, ids = _top_k(choice, k)
+        _, ids = jax.lax.top_k(choice, k)
         vals = jnp.take_along_axis(scores, ids, axis=-1)
     else:
         raise ValueError(f"scoring={m.scoring!r}: 'softmax' or 'sigmoid'")
@@ -140,24 +126,6 @@ def route(logits, m, bias=None):
     if m.routed_scaling_factor != 1.0:
         vals = vals * m.routed_scaling_factor
     return vals, ids, scores
-
-
-def _hint(x, spec_axes, enable):
-    """§Perf sharding hint: without it GSPMD replicates the (E, C, D)
-    expert buffers across the data axis and every data rank computes every
-    expert — the dominant waste in the MoE baselines (EXPERIMENTS §Perf)."""
-    if not enable:
-        return x
-    from jax.sharding import PartitionSpec as P
-    try:
-        from jax._src.mesh import thread_resources
-        names = thread_resources.env.physical_mesh.axis_names
-        if "pod" in names:   # multi-pod: data-parallel axes are (pod, data)
-            spec_axes = [("pod", "data") if a == "data" else a
-                         for a in spec_axes]
-        return jax.lax.with_sharding_constraint(x, P(*spec_axes))
-    except Exception:
-        return x   # no mesh (single-device tests)
 
 
 def moe_layer(p, x, cfg):
@@ -169,14 +137,11 @@ def moe_layer(p, x, cfg):
 def moe_layer_counted(p, x, cfg):
     """``moe_layer`` and, per routing group, two int32 counts
     ``(G, 2)``: the assignments routed to held experts, and those kept
-    under capacity.  A layer with no capacity takes the dropless path; a
-    plain layer routed as one group the flat one; every other layer the
-    grouped one (one group when ``moe_groups`` is 1)."""
+    under capacity.  A layer with no capacity takes the dropless path,
+    every other layer the grouped one, at ``moe_groups`` groups."""
     if cfg.moe.capacity_factor is None:
         return _moe_layer_dropless(p, x, cfg)
-    if cfg.moe_groups > 1 or not _plain(cfg.moe):
-        return moe_layer_grouped(p, x, cfg)
-    return _moe_layer_flat(p, x, cfg)
+    return moe_layer_grouped(p, x, cfg)
 
 
 def _aux_loss(probs, ids, m):
@@ -204,8 +169,6 @@ def _moe_layer_dropless(p, x, cfg):
     k, g = m.top_k, cfg.moe_groups
     offset, held = held_range(m)
     t = DROPLESS_TILE
-    if cfg.moe_combine_shardmap:
-        raise NotImplementedError("shard_map dispatch keeps a capacity")
     xf = x.reshape(n, d)
 
     with jax.named_scope("router"):
@@ -259,84 +222,6 @@ def _moe_layer_dropless(p, x, cfg):
     return out.reshape(b, s, d), aux, counts
 
 
-def _moe_layer_flat(p, x, cfg):
-    """x: (B,S,D) -> (out (B,S,D), aux_loss scalar, counts (1, 2))."""
-    m = cfg.moe
-    b, s, d = x.shape
-    n = b * s
-    e, k = m.num_experts, m.top_k
-    xf = x.reshape(n, d)
-
-    with jax.named_scope("router"):
-        router_logits = xf.astype(jnp.float32) @ p["router"]      # (N,E)
-        probs = jax.nn.softmax(router_logits, axis=-1)
-        top_vals, top_ids = _top_k(probs, k)                      # (N,k)
-        top_vals = top_vals / jnp.clip(
-            jnp.sum(top_vals, axis=-1, keepdims=True), 1e-9)      # renorm
-
-        # ---- load-balancing auxiliary loss (switch-style) ------------
-        me = jnp.mean(probs, axis=0)                              # (E,)
-        ce = jnp.mean(
-            jnp.sum(jax.nn.one_hot(top_ids, e, dtype=jnp.float32),
-                    axis=1), axis=0)
-        aux = e * jnp.sum(me * ce) * m.router_aux_weight
-
-    hints = cfg.moe_shard_hints
-    with jax.named_scope("dispatch"):
-        # ---- sort-based rank-within-expert ---------------------------
-        capacity = int(max(k, round(m.capacity_factor * n * k / e)))
-        flat_ids = top_ids.reshape(-1)                            # (N*k,)
-        sort_idx = jnp.argsort(flat_ids)                          # stable
-        sorted_ids = flat_ids[sort_idx]
-        counts = jnp.bincount(flat_ids, length=e)                 # (E,)
-        starts = jnp.cumsum(counts) - counts                      # exclusive
-        ranks_sorted = jnp.arange(n * k) - starts[sorted_ids]
-        ranks = jnp.zeros_like(ranks_sorted).at[sort_idx].set(ranks_sorted)
-
-        keep = ranks < capacity
-        slot = jnp.where(keep, flat_ids * capacity + ranks, e * capacity)
-        counts = jnp.stack([jnp.int32(n * k),
-                            jnp.sum(keep, dtype=jnp.int32)])[None]
-
-        # ---- scatter tokens into the expert buffer -------------------
-        token_of = jnp.repeat(jnp.arange(n), k)                   # (N*k,)
-        buf = jnp.zeros((e * capacity + 1, d), x.dtype)
-        buf = buf.at[slot].set(xf[token_of], mode="drop")
-        expert_in = _hint(buf[:-1].reshape(e, capacity, d),
-                          ("model", "data", None), hints)
-
-    with jax.named_scope("held_ffn"):
-        # batched over experts
-        h = jnp.einsum("ecd,edf->ecf", expert_in, p["w_up"])
-        if "w_gate" in p:
-            h = _act(jnp.einsum("ecd,edf->ecf", expert_in, p["w_gate"]),
-                     cfg.act) * h
-        else:
-            h = _act(h, cfg.act)
-        h = _hint(h, ("model", "data", None), hints)
-        expert_out = _hint(jnp.einsum("ecf,efd->ecd", h, p["w_down"]),
-                           ("model", "data", None), hints)
-
-    with jax.named_scope("combine"):
-        # gather surviving assignments back
-        flat_out = expert_out.reshape(e * capacity, d)
-        gathered = jnp.where(
-            keep[:, None], flat_out[jnp.minimum(slot, e * capacity - 1)],
-            jnp.zeros((), x.dtype))                                # (N*k, D)
-        gathered = _hint(gathered, ("data", None), hints)
-        # fused f32 contraction over k — never materializes an f32 (N·k, D)
-        out = jnp.einsum("nkd,nk->nd", gathered.reshape(n, k, d),
-                         top_vals.astype(jnp.float32),
-                         preferred_element_type=jnp.float32).astype(x.dtype)
-        out = _hint(out, ("data", None), hints)
-
-    # ---- shared experts (always-on path) ------------------------------
-    if "shared_up" in p:
-        out = out + _shared_ffn(p, xf, cfg)
-
-    return out.reshape(b, s, d), aux, counts
-
-
 def _shared_ffn(p, xf, cfg):
     """The shared experts over every token ``xf`` (N, D)."""
     with jax.named_scope("shared_ffn"):
@@ -349,19 +234,14 @@ def _shared_ffn(p, xf, cfg):
 
 
 def moe_layer_grouped(p, x, cfg):
-    """§Perf (B2): group-local routing; returns ``moe_layer_counted``'s
-    triple.
+    """A layer under a capacity; returns ``moe_layer_counted``'s triple.
 
-    Tokens are split into ``moe_groups`` groups aligned with the
-    data-parallel axis; ranking / capacity / dispatch happen *inside* each
-    group (a batched dimension sharded over ``data``), so the global
-    argsort, rank scatter and gather collectives of the flat path
-    disappear.  The expert buffers carry the group axis:
-    (G→data, E→model, C, D) — the expert einsum is fully sharded with no
-    resharding, and only the combine-side gather crosses the model axis
-    (the all-to-all equivalent).  Capacity is per group:
-    C_loc = cf·n_loc·k/E (same expected load, stricter tail — the usual
-    EP trade-off).
+    Tokens are split into ``moe_groups`` groups (one is the whole batch);
+    ranking, capacity and dispatch happen inside each group, a batched
+    dimension that GSPMD may shard over the data axis.  The expert
+    buffers carry the group axis, (G, held, C, D), so one batched einsum
+    runs every held expert of every group.  Capacity is per group:
+    C_loc = cf·n_loc·k/E (same expected load, stricter tail).
 
     A layer that holds a share of the experts ranks every assignment as
     above and keeps those of its own experts.
@@ -374,10 +254,7 @@ def moe_layer_grouped(p, x, cfg):
     g = cfg.moe_groups
     assert n % g == 0, (n, g)
     nl = n // g
-    hints = cfg.moe_shard_hints
-    if cfg.moe_combine_shardmap and held < e:
-        raise NotImplementedError("shard_map dispatch holds every expert")
-    xg = _hint(x.reshape(g, nl, d), ("data", None, None), hints)
+    xg = x.reshape(g, nl, d)
 
     with jax.named_scope("router"):
         router_logits = xg.astype(jnp.float32) @ p["router"]      # (G,NL,E)
@@ -409,7 +286,7 @@ def moe_layer_grouped(p, x, cfg):
         return slot, keep, jnp.stack([routed,
                                       jnp.sum(keep, dtype=jnp.int32)])
 
-    def build_buf(xl, slot_g, keep_g):
+    def build_buf(xl, slot_g):
         token_of = jnp.repeat(jnp.arange(nl), k)
         buf = jnp.zeros((rows + 1, d), xl.dtype)
         buf = buf.at[slot_g].set(xl[token_of], mode="drop")
@@ -417,14 +294,7 @@ def moe_layer_grouped(p, x, cfg):
 
     with jax.named_scope("dispatch"):
         slot, keep, tallies = jax.vmap(rank_group)(top_ids)
-        if cfg.moe_combine_shardmap:
-            # per model rank, build ONLY the local experts' buffers — the
-            # forward dispatch needs no collective at all (§Perf B6)
-            expert_in = _dispatch_shardmap(xg, slot, keep, nl=nl, e=e,
-                                           cap=cap, d=d, k=k)
-        else:
-            expert_in = jax.vmap(build_buf)(xg, slot, keep)
-        expert_in = _hint(expert_in, ("data", "model", None, None), hints)
+        expert_in = jax.vmap(build_buf)(xg, slot)
 
     with jax.named_scope("held_ffn"):
         h = jnp.einsum("gecd,edf->gecf", expert_in, p["w_up"])
@@ -433,14 +303,12 @@ def moe_layer_grouped(p, x, cfg):
                      cfg.act) * h
         else:
             h = _act(h, cfg.act)
-        h = _hint(h, ("data", "model", None, None), hints)
-        expert_out = _hint(jnp.einsum("gecf,efd->gecd", h, p["w_down"]),
-                           ("data", "model", None, None), hints)
+        expert_out = jnp.einsum("gecf,efd->gecd", h, p["w_down"])
 
     def combine_group(outs, slot_g, keep_g, vals):
         # scatter-add combine: weighted contributions accumulate straight
-        # into the (NL, D) token buffer, so the cross-shard reduction is
-        # k× smaller than reducing the gathered (NL·k, D) tensor (§Perf B3)
+        # into the (NL, D) token buffer, so a reduction across shards is
+        # k× smaller than one over the gathered (NL·k, D) tensor
         flat = outs.reshape(rows, d)
         contrib = flat[jnp.minimum(slot_g, rows - 1)] * \
             vals.reshape(-1)[:, None].astype(flat.dtype)     # (NL*k, D)
@@ -451,133 +319,12 @@ def moe_layer_grouped(p, x, cfg):
         return acc[:-1]
 
     with jax.named_scope("combine"):
-        if cfg.moe_combine_shardmap:
-            out = _combine_shardmap(expert_out, slot, keep, top_vals,
-                                    nl=nl, e=e, cap=cap, d=d, k=k)
-        else:
-            out = jax.vmap(combine_group)(expert_out, slot, keep, top_vals)
-        out = _hint(out.astype(x.dtype), ("data", None, None), hints)
-        out = out.reshape(b, s, d)
+        out = jax.vmap(combine_group)(expert_out, slot, keep, top_vals)
+        out = out.astype(x.dtype).reshape(b, s, d)
 
     if "shared_up" in p:
         out = out + _shared_ffn(p, x.reshape(n, d), cfg).reshape(b, s, d)
     return out, aux, tallies
-
-
-def _combine_shardmap(expert_out, slot, keep, vals, *, nl, e, cap, d, k):
-    """§Perf (B4): explicit-collective combine.
-
-    GSPMD's gather-based combine all-reduces the k-expanded (NL·k, D)
-    tensor (B3 showed it won't exploit scatter linearity).  Under
-    shard_map each model rank gathers *only its local experts'* outputs,
-    scatter-adds its partial (NL, D) token buffer, and a single
-    ``psum`` over 'model' finishes the job — k× less wire traffic, by
-    construction.
-    """
-    import functools
-
-    from jax._src.mesh import thread_resources
-    from jax.sharding import PartitionSpec as P
-
-    mesh = thread_resources.env.physical_mesh
-    if mesh.empty or "model" not in mesh.axis_names or \
-            e % mesh.shape["model"]:
-        # fallback: no mesh (tests) or non-divisible expert count
-        return _combine_gspmd(expert_out, slot, keep, vals, nl=nl, e=e,
-                              cap=cap, d=d, k=k)
-    dp = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
-    dpa = dp if len(dp) > 1 else dp[0]
-
-    def local(eo, sl, kp, vl):
-        # eo (gl, el, cap, d); sl/kp (gl, NL·k); vl (gl, NL, k)
-        gl, el = eo.shape[0], eo.shape[1]
-        midx = jax.lax.axis_index("model")
-        base = midx * el * cap
-
-        def one(eo_g, sl_g, kp_g, vl_g):
-            loc = sl_g - base
-            ok = kp_g & (loc >= 0) & (loc < el * cap)
-            flat = eo_g.reshape(el * cap, d)
-            contrib = flat[jnp.clip(loc, 0, el * cap - 1)] * \
-                vl_g.reshape(-1)[:, None].astype(flat.dtype)
-            token_of = jnp.repeat(jnp.arange(nl), k)
-            idx = jnp.where(ok, token_of, nl)
-            acc = jnp.zeros((nl + 1, d), jnp.float32)
-            acc = acc.at[idx].add(contrib.astype(jnp.float32),
-                                  mode="drop")
-            return acc[:-1]
-
-        part = jax.vmap(one)(eo, sl, kp, vl)
-        return jax.lax.psum(part.astype(jnp.bfloat16), "model")
-
-    fn = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(dpa, "model", None, None), P(dpa, None), P(dpa, None),
-                  P(dpa, None, None)),
-        out_specs=P(dpa, None, None), check_vma=False)
-    return fn(expert_out, slot, keep, vals).astype(jnp.float32)
-
-
-def _dispatch_shardmap(xg, slot, keep, *, nl, e, cap, d, k):
-    """§Perf (B6): collective-free forward dispatch.
-
-    Each (data, model) rank scatters its local tokens into the buffer
-    slice of its *own* experts only; the result is born sharded
-    (G→data, E→model) with zero forward communication.  The backward pass
-    is a single psum of the (G, NL, D) token-gradient — the mirror of the
-    B4 combine.
-    """
-    from jax._src.mesh import thread_resources
-    from jax.sharding import PartitionSpec as P
-
-    mesh = thread_resources.env.physical_mesh
-    if mesh.empty or "model" not in mesh.axis_names or \
-            e % mesh.shape["model"]:
-        def build(xl, sl, kp):
-            token_of = jnp.repeat(jnp.arange(nl), k)
-            buf = jnp.zeros((e * cap + 1, d), xl.dtype)
-            buf = buf.at[sl].set(xl[token_of], mode="drop")
-            return buf[:-1].reshape(e, cap, d)
-        return jax.vmap(build)(xg, slot, keep)
-    msize = mesh.shape["model"]
-    el = e // msize
-    dp = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
-    dpa = dp if len(dp) > 1 else dp[0]
-
-    def local(xl, sl, kp):
-        midx = jax.lax.axis_index("model")
-        base = midx * el * cap
-
-        def one(x_g, s_g, k_g):
-            loc = s_g - base
-            ok = k_g & (loc >= 0) & (loc < el * cap)
-            idx = jnp.where(ok, loc, el * cap)
-            token_of = jnp.repeat(jnp.arange(nl), k)
-            buf = jnp.zeros((el * cap + 1, d), x_g.dtype)
-            buf = buf.at[idx].set(x_g[token_of], mode="drop")
-            return buf[:-1].reshape(el, cap, d)
-
-        return jax.vmap(one)(xl, sl, kp)
-
-    fn = jax.shard_map(local, mesh=mesh,
-                       in_specs=(P(dpa, None, None), P(dpa, None),
-                                 P(dpa, None)),
-                       out_specs=P(dpa, "model", None, None),
-                       check_vma=False)
-    return fn(xg, slot, keep)
-
-
-def _combine_gspmd(expert_out, slot, keep, vals, *, nl, e, cap, d, k):
-    def combine_group(outs, slot_g, keep_g, vl):
-        flat = outs.reshape(e * cap, d)
-        contrib = flat[jnp.minimum(slot_g, e * cap - 1)] * \
-            vl.reshape(-1)[:, None].astype(flat.dtype)
-        token_of = jnp.repeat(jnp.arange(nl), k)
-        idx = jnp.where(keep_g, token_of, nl)
-        acc = jnp.zeros((nl + 1, d), jnp.float32)
-        acc = acc.at[idx].add(contrib.astype(jnp.float32), mode="drop")
-        return acc[:-1]
-    return jax.vmap(combine_group)(expert_out, slot, keep, vals)
 
 
 def moe_layer_dense_ref(p, x, cfg):

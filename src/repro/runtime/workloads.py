@@ -419,15 +419,13 @@ class MoEWorkloadSpec(WorkloadSpec):
 class _MoELayerModelCfg:
     """The slice of ``configs.base.ModelConfig`` that ``models.moe``
     reads, so a workload spec can drive ``moe_layer`` without
-    fabricating a whole transformer config.  Serving runs float32 on
-    the flat (single-group, hint-free) path — deterministic on CPU."""
+    fabricating a whole transformer config.  Serving runs float32, with
+    ``moe_groups`` set to the blocks of a dispatch (``_route_per_block``)."""
     moe: MoEConfig
     d_model: int
     act: str = "silu"
     mlp_gated: bool = True
     moe_groups: int = 1
-    moe_shard_hints: bool = False
-    moe_combine_shardmap: bool = False
 
     @property
     def jnp_dtype(self):

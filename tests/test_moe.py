@@ -91,7 +91,7 @@ def test_aux_loss_prefers_balance():
 
 
 def test_grouped_routing_matches_dense_oracle():
-    """§Perf B2 path: group-local routing == dense oracle at high cap."""
+    """Group-local routing (four groups) == dense oracle at high cap."""
     cfg = _cfg(cf=8.0).with_overrides(moe_groups=4)
     p = moe_mod.init_moe(jax.random.PRNGKey(0), cfg)
     x = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
@@ -101,9 +101,11 @@ def test_grouped_routing_matches_dense_oracle():
                                rtol=2e-4, atol=2e-4)
 
 
-def test_shardmap_dispatch_combine_multidevice():
-    """§Perf B4/B6 path on a real (4,2) mesh: shard_map dispatch/combine
-    == dense oracle, and gradients flow (subprocess, 8 host devices)."""
+def test_grouped_dispatch_under_gspmd_multidevice():
+    """The grouped path on a real (4,2) mesh, the experts placed over
+    ``model`` by ``parallel.sharding``'s rules and the tokens over
+    ``data``: GSPMD's partitioning == dense oracle, and gradients flow
+    (subprocess, 8 host devices)."""
     import subprocess
     import sys
     import textwrap
@@ -113,32 +115,38 @@ def test_shardmap_dispatch_combine_multidevice():
         import sys
         sys.path.insert(0, "src")
         import dataclasses, jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import smoke_config
         from repro.launch.mesh import auto_mesh
         from repro.models import moe as moe_mod
+        from repro.parallel.sharding import ShardingRules
         cfg = smoke_config('qwen3-moe-30b-a3b').with_overrides(
             dtype='float32')
         cfg = cfg.with_overrides(
             moe=dataclasses.replace(cfg.moe, capacity_factor=8.0),
-            moe_groups=4, moe_combine_shardmap=True, moe_shard_hints=True)
+            moe_groups=4)
         p = moe_mod.init_moe(jax.random.PRNGKey(0), cfg)
         x = 0.1 * jax.random.normal(jax.random.PRNGKey(1),
                                     (4, 16, cfg.d_model))
         mesh = auto_mesh((4, 2), ('data', 'model'))
-        with mesh:
-            out, _ = jax.jit(lambda p, x: moe_mod.moe_layer(p, x, cfg))(p, x)
-            g = jax.jit(jax.grad(
-                lambda p, x: moe_mod.moe_layer(p, x, cfg)[0].sum()))(p, x)
+        place = ShardingRules(cfg, mesh, mode='tp').params_sharding(
+            {'moe': p})['moe']
+        assert place['w_up'].spec[0] == 'model', place['w_up'].spec
+        ps = jax.device_put(p, place)
+        xs = jax.device_put(x, NamedSharding(mesh, P('data')))
+        out, _ = jax.jit(lambda p, x: moe_mod.moe_layer(p, x, cfg))(ps, xs)
+        g = jax.jit(jax.grad(
+            lambda p, x: moe_mod.moe_layer(p, x, cfg)[0].sum()))(ps, xs)
         ref = moe_mod.moe_layer_dense_ref(p, x, cfg)
         err = float(jnp.max(jnp.abs(out - ref)))
         gn = sum(float(jnp.sum(jnp.abs(l))) for l in jax.tree.leaves(g))
         assert err < 5e-3, err
         assert gn > 0
-        print("SHARDMAP_MOE_OK", err)
+        print("GSPMD_MOE_OK", err)
     """)
     out = subprocess.run([sys.executable, "-c", prog], cwd=".",
                          capture_output=True, text=True, timeout=600)
-    assert "SHARDMAP_MOE_OK" in out.stdout, out.stdout + out.stderr
+    assert "GSPMD_MOE_OK" in out.stdout, out.stdout + out.stderr
 
 
 # ---------------------------------------------------------------------------
